@@ -34,6 +34,7 @@ import subprocess
 import sys
 import textwrap
 
+import jax
 import numpy as np
 
 from repro.core.pipeline import CapacityPolicy
@@ -148,7 +149,14 @@ def fused_main() -> None:
           f"{n_exec} step executable(s) total (<= {pol.n_buckets} buckets), "
           f"results bit-identical to solo runs")
 
-    # leg 2: partitioned serving parity on 4 forced host devices
+    # leg 2: partitioned serving parity on 4 forced host devices.  The child
+    # exists only for forced CPU devices: on a chip this process already
+    # holds the device, and a child cannot share it
+    if jax.default_backend() == "tpu":
+        raise SystemExit(
+            "graph_serving_smoke --fused: the partitioned leg runs in a child "
+            "on 4 forced CPU host devices; on a TPU run the partitioned path "
+            "in one process over jax.devices() (chip_smoke.py --chips 4)")
     env = dict(os.environ)
     env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=4 "
                         + env.get("XLA_FLAGS", "")).strip()
@@ -168,6 +176,9 @@ def fused_main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fused", action="store_true",
                     help="fused mixed-family tick + 4-forced-device "

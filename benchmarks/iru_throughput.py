@@ -713,11 +713,18 @@ def moe_rows(out: dict, quick: bool = False) -> None:
 def dist_rows(out: dict, quick: bool = False) -> None:
     """Partitioned-pipeline rows — one ``dist_bench`` child per shard count.
 
-    Children get a REPLACED ``XLA_FLAGS`` (bench.sh pins one host device
-    for the single-device rows; the children need P of them).  Writes the
-    weak-scaling table, its efficiency column, the measured boundary
-    compression headline, and the all-children parity flag.
+    The children exist only for forced CPU host devices: they get
+    ``JAX_PLATFORMS=cpu`` and a REPLACED ``XLA_FLAGS`` (bench.sh pins one
+    host device for the single-device rows; the children need P of them).
+    On a TPU this process holds the chip, so the rows refuse to run there.
+    Writes the weak-scaling table, its efficiency column, the measured
+    boundary compression headline, and the all-children parity flag.
     """
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "dist rows spawn children on forced CPU host devices; on a TPU "
+            "run the partitioned path in one process over jax.devices() "
+            "(chip_smoke.py --chips 4)")
     base = 32 if quick else 64
     weak: dict[str, dict] = {}
     parity = True
@@ -726,6 +733,7 @@ def dist_rows(out: dict, quick: bool = False) -> None:
         scale = round(base * p_n ** 0.5)
         env = dict(os.environ)
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={p_n}"
+        env["JAX_PLATFORMS"] = "cpu"
         env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
         r = subprocess.run(
             [sys.executable, "-m", "benchmarks.dist_bench",
@@ -912,4 +920,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 
 from benchmarks.iru_throughput import _time
 from repro.configs.base import MoEConfig
-from repro.launch.dryrun import normalize_cost_analysis
 from repro.models.common import Initializer
 from repro.models import moe as moe_mod
 
@@ -45,7 +44,7 @@ def measure(T: int, dispatch: str, params, moe, *, wall: bool = True) -> dict:
 
     compiled = jax.jit(fn).lower(jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params), x).compile()
-    cost = normalize_cost_analysis(compiled.cost_analysis())
+    cost = compiled.cost_analysis()
     out = {"T": T, "dispatch": dispatch,
            "hlo_flops": float(cost.get("flops", 0)) if cost else 0.0,
            "hlo_bytes": float(cost.get("bytes accessed", 0)) if cost else 0.0}
@@ -85,4 +84,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """§Perf hillclimb harness: re-lower one cell under modified knobs and diff
 the three roofline terms against the recorded baseline.
 
@@ -14,6 +11,7 @@ Knobs: --rules name=axis1+axis2 (empty = replicate), --attn-chunk, --micro,
 import argparse
 import dataclasses
 import json
+import os
 
 from repro.configs import LM_SHAPES, get_config
 from repro.configs.base import ParallelConfig
@@ -112,4 +110,9 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    # forced host devices for the production meshes, set only by the CLI
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    enable_compile_cache()
     main()

@@ -49,6 +49,9 @@ from repro.dist.graph_partition import (
     partitioned_pagerank_app)
 from repro.graphs.csr import partition_csr, suggest_partitions
 from repro.graphs.generators import delaunay
+from repro.launch.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 g = delaunay(scale=args.scale)
 print(f"graph: delaunay {g.n_nodes} nodes, {g.n_edges} edges")

@@ -22,6 +22,9 @@ from repro.core import CapacityPolicy, IRUConfig
 from repro.core.costmodel import Comparison, simulate_trace
 from repro.core.pipeline import FrontierPipeline
 from repro.graphs.generators import make_dataset
+from repro.launch.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--dataset", default="kron",

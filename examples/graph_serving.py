@@ -49,6 +49,9 @@ from repro.ft import QueryFaultPlan
 from repro.graphs.csr import partition_csr, tile_csr
 from repro.graphs.generators import make_dataset
 from repro.serve import GraphQuery, GraphServeConfig, GraphServingEngine
+from repro.launch.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--dataset", default="kron", choices=["kron", "delaunay"])

@@ -31,6 +31,9 @@ from repro.models.moe import init_moe, moe_ffn
 from repro.moe import (capacity, dispatch_stats, format_stats, moe_hash,
                        moe_hash_ep, plan_dispatch)
 from repro.moe.dispatch import _route, execute_plan
+from repro.launch.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 T, D, E, k, F = 256, 64, 8, 2, 96
 moe = MoEConfig(n_experts=E, top_k=k, d_ff=F, capacity_factor=1.0)

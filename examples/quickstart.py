@@ -25,6 +25,9 @@ from repro.core import (
     mean_accesses_per_group,
 )
 from repro.graphs.generators import make_dataset
+from repro.launch.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 rng = np.random.default_rng(0)
 

@@ -11,7 +11,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.optim.adamw import dequantize_i8, quantize_i8
@@ -59,10 +58,10 @@ def allreduce_int8(x: jax.Array, mesh, axis: str) -> jax.Array:
         deq = dequantize_i8(quantize_i8(local), local.shape)
         return jax.lax.psum(deq, axis)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=P(axis, *([None] * (x.ndim - 1))),
         out_specs=P(*([None] * (x.ndim - 1))),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x)

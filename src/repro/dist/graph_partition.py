@@ -42,8 +42,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.iru import IRUConfig
 from repro.core.pipeline import (CapacityPolicy, FrontierApp, _merge_identity,
@@ -327,7 +326,9 @@ class PartitionedFrontierPipeline:
             raise ValueError(
                 f"mesh axis {AXIS!r} has size {mesh.shape.get(AXIS)}, "
                 f"partition has {part.n_parts} shards")
-        self.part = part
+        # one shard per device, placed once: an unplaced partition would sit
+        # whole on the default device and be re-split on every superstep
+        self.part = jax.device_put(part, NamedSharding(mesh, P(AXIS)))
         self.papp = papp
         self.mesh = mesh
         self.mode = mode
@@ -355,15 +356,15 @@ class PartitionedFrontierPipeline:
         spec = P(AXIS)
         rep = P()
         self._step_b = tuple(
-            jax.jit(shard_map(
+            jax.jit(jax.shard_map(
                 functools.partial(self._superstep, bucket=b),
                 mesh=mesh, in_specs=(spec, spec, spec, spec),
-                out_specs=(spec, spec, spec, rep, rep), check_rep=False),
+                out_specs=(spec, spec, spec, rep, rep), check_vma=False),
                 donate_argnums=(1, 2, 3))
             for b in range(len(self.buckets)))
-        self._predict = jax.jit(shard_map(
+        self._predict = jax.jit(jax.shard_map(
             self._predict_impl, mesh=mesh, in_specs=(spec, spec),
-            out_specs=(rep, rep), check_rep=False))
+            out_specs=(rep, rep), check_vma=False))
 
     # -- compiled bodies (run per shard inside shard_map) ------------------
     def _local_graph(self, part: GraphPartition) -> CSRGraph:
@@ -415,11 +416,20 @@ class PartitionedFrontierPipeline:
                 return i
         return len(self.buckets) - 1
 
-    def run(self, source: int = 0) -> jax.Array:
+    def init(self, source: int = 0):
+        """Initial ``(state, mask, ef_buf)``, placed one shard per device:
+        the first superstep then sees the same input shardings as every
+        later one, so the step compiles once."""
         part = self.part
         state, mask = self.papp.init(part, source)
         ef_buf = jnp.zeros(
             (part.n_parts, part.n_parts, max(part.lane_cap, 1)), jnp.float32)
+        return jax.device_put((state, mask, ef_buf),
+                              NamedSharding(self.mesh, P(AXIS)))
+
+    def run(self, source: int = 0) -> jax.Array:
+        part = self.part
+        state, mask, ef_buf = self.init(source)
         self.supersteps = 0
         last_b = None
         it, cont = 0, True

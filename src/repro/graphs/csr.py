@@ -41,9 +41,8 @@ class CSRGraph:
     def edge_sources(self) -> jax.Array:
         """int32[n_edges] source node of each edge (expanded row_ptr).
 
-        Pure-jnp (``searchsorted`` over ``row_ptr`` — the same
-        load-balanced-search form :func:`expand_frontier` uses), so it is
-        legal under ``jit``: edge ``e`` belongs to the last node whose CSR
+        Pure-jnp (``searchsorted`` over ``row_ptr``), so it is legal
+        under ``jit``: edge ``e`` belongs to the last node whose CSR
         range starts at or before ``e`` (degree-0 nodes contribute repeated
         ``row_ptr`` entries and are skipped by ``side="right"``).
         """
@@ -149,8 +148,9 @@ def expand_frontier(
     full CSR range; lanes are laid out node-major in frontier order — the
     Gunrock "advance" operator as a shape-stable gather, legal under
     ``jit``/``lax.while_loop``.  Work per lane is the load-balanced-search
-    form: a ``searchsorted`` over the frontier's degree prefix sum locates
-    the owning node of every output lane in O(log F).
+    form without the search: each frontier slot marks the first lane of its
+    range in the degree prefix sum, and a running max over lanes gives every
+    output lane its owning node.
 
     ``gather`` selects how ``col_idx`` is serviced: ``"xla"`` (native take)
     or ``"pallas"`` (the block-reuse kernel of ``kernels/coalesced_gather``
@@ -193,10 +193,15 @@ def expand_frontier(
     total = cum[F - 1]
     lane = jnp.arange(cap, dtype=jnp.int32)
     valid = lane < total
-    k = jnp.clip(jnp.searchsorted(cum, lane, side="right"), 0, F - 1)
-    k = k.astype(jnp.int32)
-    base = cum[k] - counts[k]
-    raw = starts[k] + (lane - base)
+    # owner slot of every lane: each slot marks its first lane and a running
+    # max carries it across its range (a degree-0 slot shares its first lane
+    # with the next slot and loses the max to it).  A per-lane binary search
+    # over ``cum`` is ~log2(F) dependent gathers per lane: on a TPU v5e it
+    # made the expansion about 97% of a BFS step
+    first = cum - counts
+    k = jax.lax.cummax(jnp.zeros((cap,), jnp.int32).at[first].max(
+        jnp.arange(F, dtype=jnp.int32), mode="drop"))
+    raw = (starts - first)[k] + lane
     # padding repeats the LAST real offset (not 0): the offset stream stays
     # monotone non-decreasing end to end, so a trailing partial group does
     # not break the gather kernel's two-window contract
@@ -355,14 +360,15 @@ def from_edges(
     keep = (src != dst) & (src >= 0) & (dst >= 0) & (src < n_nodes) & (dst < n_nodes)
     src, dst, weights = src[keep], dst[keep], weights[keep]
     if dedup:
-        key = src * n_nodes + dst
-        _, first = np.unique(key, return_index=True)
-        src, dst, weights = src[first], dst[first], weights[first]
-    order = np.lexsort((dst, src))
-    src, dst, weights = src[order], dst[order], weights[order]
-    row_ptr = np.zeros(n_nodes + 1, np.int64)
-    np.add.at(row_ptr, src + 1, 1)
-    row_ptr = np.cumsum(row_ptr)
+        # unique keys come out ascending in (src, dst): the CSR order, so no
+        # second sort (Graph500-scale builds are host set-up time)
+        key, first = np.unique(src * n_nodes + dst, return_index=True)
+        src, dst, weights = key // n_nodes, key % n_nodes, weights[first]
+    else:
+        order = np.lexsort((dst, src))
+        src, dst, weights = src[order], dst[order], weights[order]
+    row_ptr = np.concatenate(
+        [[0], np.cumsum(np.bincount(src, minlength=n_nodes))])
     return CSRGraph(
         row_ptr=jnp.asarray(row_ptr, jnp.int32),
         col_idx=jnp.asarray(dst, jnp.int32),

@@ -20,37 +20,38 @@ from repro.kernels.coalesced_gather.ref import coalesced_gather_ref
 from repro.kernels.iru_reorder.ops import resolve_interpret
 
 
-@functools.partial(jax.jit, static_argnames=("group", "window", "use_pallas", "interpret"))
-def coalesced_gather(
-    table: jax.Array,
-    indices: jax.Array,
-    *,
-    group: int = 8,
-    window: int = 128,
-    use_pallas: bool = True,
-    interpret: Optional[bool] = None,
-) -> jax.Array:
-    if not use_pallas:
-        return coalesced_gather_ref(table, indices)
-    interpret = resolve_interpret(interpret)
-    ok = window_contract_ok(indices, group=group, window=window)
+def _gather_columns(columns, indices, interpret):
+    """``c[indices]`` for every 1-D column, through the kernel when the
+    window contract holds and through ``jnp.take`` otherwise."""
     return jax.lax.cond(
-        ok,
-        lambda t, i: coalesced_gather_pallas(t, i, group=group, window=window, interpret=interpret),
-        coalesced_gather_ref,
-        table,
+        window_contract_ok(indices),
+        lambda cs, i: coalesced_gather_pallas(cs, i, interpret=interpret),
+        lambda cs, i: tuple(coalesced_gather_ref(c, i) for c in cs),
+        tuple(columns),
         indices,
     )
 
 
-@functools.partial(jax.jit, static_argnames=("group", "window", "interpret"))
+@functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
+def coalesced_gather(
+    table: jax.Array,
+    indices: jax.Array,
+    *,
+    use_pallas: bool = True,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """``table[indices]`` for a ``[V]`` table of 32-bit values."""
+    if not use_pallas:
+        return coalesced_gather_ref(table, indices)
+    return _gather_columns((table,), indices, resolve_interpret(interpret))[0]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def csr_edge_gather(
     col_idx: jax.Array,
     offsets: jax.Array,
     weights: Optional[jax.Array] = None,
     *,
-    group: int = 8,
-    window: int = 128,
     interpret: Optional[bool] = None,
 ):
     """Edge-array gather ``col_idx[offsets]`` (and optionally
@@ -59,22 +60,14 @@ def csr_edge_gather(
     This is the expansion path of ``graphs.csr.expand_frontier``: an
     ascending node frontier makes CSR offsets monotone non-decreasing, so
     consecutive lanes read inside narrow aligned windows — the kernel's
-    exact contract (violations fall back to the native gather inside
-    ``coalesced_gather``, trading coalescing for progress, never
-    correctness).  When ``weights`` is given, both edge arrays ride ONE
-    kernel pass: the int32 column ids bitcast to f32 and pack with the
-    weights as a two-column table, so each HBM window is staged exactly
-    once for both gathers.
+    exact contract (violations fall back to the native gather, trading
+    coalescing for progress, never correctness).  When ``weights`` is
+    given, both edge arrays ride ONE kernel pass over the same windows.
     """
+    interpret = resolve_interpret(interpret)
     if weights is None:
-        table = jax.lax.bitcast_convert_type(
-            col_idx.astype(jnp.int32), jnp.float32)[:, None]
-        out = coalesced_gather(table, offsets, group=group, window=window,
-                               interpret=interpret)
-        return jax.lax.bitcast_convert_type(out[:, 0], jnp.int32)
-    table = jnp.stack(
-        [jax.lax.bitcast_convert_type(col_idx.astype(jnp.int32), jnp.float32),
-         weights.astype(jnp.float32)], axis=1)
-    out = coalesced_gather(table, offsets, group=group, window=window,
-                           interpret=interpret)
-    return (jax.lax.bitcast_convert_type(out[:, 0], jnp.int32), out[:, 1])
+        return _gather_columns((col_idx.astype(jnp.int32),), offsets,
+                               interpret)[0]
+    return _gather_columns(
+        (col_idx.astype(jnp.int32), weights.astype(jnp.float32)), offsets,
+        interpret)

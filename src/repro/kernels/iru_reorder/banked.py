@@ -38,7 +38,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels.iru_reorder.batched import (
@@ -215,13 +214,13 @@ def hash_reorder_banked(
             # the tag table (when present) is replicated across the mesh —
             # every shard's rows consult the same index → family map
             extra = () if tag_table is None else (P(),)
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 rows_stage, mesh=mesh,
                 in_specs=(P(axis), P(axis), P(axis), P(axis),
                           P(axis)) + extra,
                 out_specs=(P(axis), P(axis), P(axis), P(axis),
                            P(axis), P(axis)),
-                check_rep=False,
+                check_vma=False,
             )
             args = (rI, rV, rPos, rS, rValid)
             if tag_table is not None:

@@ -43,19 +43,19 @@ def _hash_set(key: jax.Array, num_sets: int) -> jax.Array:
 
 
 def _store1(ref, i, val):
-    pl.store(ref, (pl.ds(i, 1),), val.reshape(1))
+    ref[pl.ds(i, 1)] = val.reshape(1)
 
 
 def _store_cell(ref, s, j, val):
-    pl.store(ref, (pl.ds(s, 1), pl.ds(j, 1)), val.reshape(1, 1))
+    ref[pl.ds(s, 1), pl.ds(j, 1)] = val.reshape(1, 1)
 
 
 def _load_cell(ref, s, j):
-    return pl.load(ref, (pl.ds(s, 1), pl.ds(j, 1))).reshape(())
+    return ref[pl.ds(s, 1), pl.ds(j, 1)].reshape(())
 
 
 def _load_row(ref, s):
-    return pl.load(ref, (pl.ds(s, 1), slice(None))).reshape(-1)
+    return ref[pl.ds(s, 1), :].reshape(-1)
 
 
 def _kernel(
@@ -106,8 +106,8 @@ def _kernel(
 
     def step(i, carry):
         head, tail = carry
-        idx = pl.load(idx_ref, (pl.ds(i, 1),)).reshape(())
-        sec = pl.load(sec_ref, (pl.ds(i, 1),)).reshape(())
+        idx = idx_ref[pl.ds(i, 1)].reshape(())
+        sec = sec_ref[pl.ds(i, 1)].reshape(())
         key = idx // epb
         s = _hash_set(key, num_sets)
         c = cnt[s]
@@ -190,14 +190,14 @@ def hash_reorder_pallas(
             jax.ShapeDtypeStruct((n,), jnp.int32),
         ],
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         scratch_shapes=[
             pltpu.VMEM((num_sets, slots), jnp.int32),

@@ -15,7 +15,7 @@ def segment_merge(
     values: jax.Array,
     *,
     op: str = "add",
-    chunk: int = 512,
+    chunk: int = 8192,
     use_pallas: bool = True,
     interpret: Optional[bool] = None,
     tags: Optional[jax.Array] = None,

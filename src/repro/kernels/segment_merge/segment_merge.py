@@ -6,10 +6,15 @@ formulation is a segmented suffix reduction over the sorted stream: the first
 lane of each run (the survivor) receives the full merged payload, all other
 lanes are deactivated.
 
-Kernel structure: the grid walks chunks of the stream in REVERSE order; a
-(carry index, carry value) pair in SMEM threads the reduction of a run that
-crosses the chunk boundary.  Within a chunk the reduction is a segmented
-``lax.associative_scan`` over the flipped block (log-depth on the VPU).
+Kernel structure: the stream is laid out lane-dense as ``[rows, 128]`` and
+the grid walks ``chunk``-element blocks of it in REVERSE order; a carry
+(index, value) pair in VMEM threads the reduction of a run that crosses the
+block boundary.  Within a block the reduction is a log-step (Hillis-Steele)
+suffix scan in flat order: step ``s`` folds lane ``i + s`` into lane ``i``
+when both hold the same index.  Sortedness makes index equality the whole
+segment test (equal indices ``s`` apart bracket a single run), and the
+shifted operand is built from ``pltpu.roll`` along lanes and sublanes, which
+Mosaic lowers natively.
 
 Contract (matches ref.segment_merge_ref):
   merged[i]    — full segment reduction, valid where survivor[i]
@@ -24,6 +29,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+_LANES = 128
+_SUBLANES = 8  # one (8, 128) tile: the block granule of 32-bit data
+
 _IDENTITY = {
     "add": lambda dt: jnp.zeros((), dt),
     "min": lambda dt: jnp.asarray(jnp.iinfo(dt).max if jnp.issubdtype(dt, jnp.integer) else jnp.inf, dt),
@@ -33,87 +41,67 @@ _IDENTITY = {
     "tagged": lambda dt: jnp.asarray(jnp.iinfo(dt).max if jnp.issubdtype(dt, jnp.integer) else jnp.inf, dt),
 }
 
-_OPS = {
-    "add": lambda a, b: a + b,
-    "min": jnp.minimum,
-    "max": jnp.maximum,
-}
+
+def _combine(op: str, a, b, tag):
+    """Fold ``b`` into ``a``.  ``tagged`` selects by the lane's own tag: a
+    fold only ever joins lanes of one run, and a run is uniform-tag."""
+    if op == "add":
+        return a + b
+    if op == "min":
+        return jnp.minimum(a, b)
+    if op == "max":
+        return jnp.maximum(a, b)
+    return jnp.where(tag != 0, a + b, jnp.minimum(a, b))
 
 
-def _kernel(idx_ref, prev_ref, val_ref, merged_ref, surv_ref, carry_idx, carry_val, *, op: str):
-    g = pl.program_id(0)
-    combine_val = _OPS[op]
-
+def _kernel(*refs, op: str):
+    if op == "tagged":
+        (idx_ref, prev_ref, val_ref, tag_ref, merged_ref, surv_ref,
+         carry_idx, carry_val) = refs
+        tag = tag_ref[...]
+    else:
+        (idx_ref, prev_ref, val_ref, merged_ref, surv_ref,
+         carry_idx, carry_val) = refs
+        tag = None
     idx = idx_ref[...]
     val = val_ref[...]
-    prev = prev_ref[...]
+    rows = idx.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, idx.shape, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, idx.shape, 1)
 
-    rid = jnp.flip(idx)
-    rval = jnp.flip(val)
+    def ahead(x, s):
+        """``x`` at flat position ``i + s`` (garbage past the block end)."""
+        if s < _LANES:
+            same_row = pltpu.roll(x, _LANES - s, 1)
+            next_row = pltpu.roll(same_row, rows - 1, 0)
+            return jnp.where(lane + s < _LANES, same_row, next_row)
+        return pltpu.roll(x, rows - s // _LANES, 0)
 
-    # Inject the carry from the chunk to our right (processed previously).
-    has_carry = g > 0
-    cmatch = has_carry & (rid[0] == carry_idx[0])
-    rval = rval.at[0].set(jnp.where(cmatch, combine_val(rval[0], carry_val[0]), rval[0]))
+    flat = row * _LANES + lane
+    s = 1
+    while s < rows * _LANES:  # static unroll: log2(chunk) steps
+        fold = (flat + s < rows * _LANES) & (ahead(idx, s) == idx)
+        val = jnp.where(fold, _combine(op, val, ahead(val, s), tag), val)
+        s *= 2
 
-    def seg_combine(left, right):
-        il, vl = left
-        ir, vr = right
-        return ir, jnp.where(il == ir, combine_val(vl, vr), vr)
+    # the run continuing into the block to our right (processed previously)
+    has_carry = pl.program_id(0) > 0
+    cidx = jnp.broadcast_to(carry_idx[...], idx.shape)
+    cval = jnp.broadcast_to(carry_val[...], idx.shape)
+    val = jnp.where(has_carry & (idx == cidx), _combine(op, val, cval, tag),
+                    val)
 
-    _, scanned = jax.lax.associative_scan(seg_combine, (rid, rval))
-    merged = jnp.flip(scanned)
+    merged_ref[...] = val
+    surv_ref[...] = (idx != prev_ref[...]).astype(jnp.int32)
 
-    merged_ref[...] = merged
-    surv_ref[...] = (idx != prev).astype(jnp.int32)
-
-    carry_idx[0] = idx[0]
-    carry_val[0] = merged[0]
-
-
-def _kernel_tagged(idx_ref, prev_ref, val_ref, tag_ref, merged_ref, surv_ref,
-                   carry_idx, carry_val):
-    """Fused-family variant: the tag rides the data as a third input stream.
-
-    Every run is uniform-tag (the tag is a function of the index), so the
-    per-lane combine selects min or add by the RIGHT operand's tag — inside
-    a run both operands share it, across runs the result is discarded, and
-    the segmented scan stays associative exactly as in the single-op kernel.
-    The cross-chunk carry needs no tag slot: the match lane's own tag is the
-    carried run's tag.
-    """
-    g = pl.program_id(0)
-
-    def comb(a, b, t):
-        return jnp.where(t != 0, a + b, jnp.minimum(a, b))
-
-    idx = idx_ref[...]
-    val = val_ref[...]
-    prev = prev_ref[...]
-    tag = tag_ref[...]
-
-    rid = jnp.flip(idx)
-    rval = jnp.flip(val)
-    rtag = jnp.flip(tag)
-
-    has_carry = g > 0
-    cmatch = has_carry & (rid[0] == carry_idx[0])
-    rval = rval.at[0].set(
-        jnp.where(cmatch, comb(rval[0], carry_val[0], rtag[0]), rval[0]))
-
-    def seg_combine(left, right):
-        il, vl, _tl = left
-        ir, vr, tr = right
-        return ir, jnp.where(il == ir, comb(vl, vr, tr), vr), tr
-
-    _, scanned, _ = jax.lax.associative_scan(seg_combine, (rid, rval, rtag))
-    merged = jnp.flip(scanned)
-
-    merged_ref[...] = merged
-    surv_ref[...] = (idx != prev).astype(jnp.int32)
-
-    carry_idx[0] = idx[0]
-    carry_val[0] = merged[0]
+    # the next block (to the left) continues the run that starts this one:
+    # carry lane 0 out as a (1, 1) reduction (every other lane masked to the
+    # min identity)
+    first = (row == 0) & (lane == 0)
+    carry_idx[...] = jnp.min(
+        jnp.where(first, idx, _IDENTITY["min"](idx.dtype)), keepdims=True)
+    carry_val[...] = jnp.min(
+        jnp.where(first, val, _IDENTITY["min"](val.dtype)), keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("op", "chunk", "interpret"))
@@ -123,11 +111,16 @@ def segment_merge_pallas(
     tags: jax.Array | None = None,
     *,
     op: str = "add",
-    chunk: int = 512,
+    chunk: int = 8192,
     interpret: bool = True,
 ):
+    """``chunk`` elements per grid step; a multiple of one (8, 128) tile."""
     if (op == "tagged") != (tags is not None):
         raise ValueError("op='tagged' and tags go together")
+    if chunk % (_SUBLANES * _LANES) != 0:
+        raise ValueError(
+            f"chunk={chunk} must be a multiple of {_SUBLANES * _LANES} "
+            f"(whole ({_SUBLANES}, {_LANES}) tiles)")
     n = sorted_indices.shape[0]
     dt = values.dtype
     ident = _IDENTITY[op](dt)
@@ -137,34 +130,31 @@ def segment_merge_pallas(
     prev = jnp.concatenate([idx[:1] - 1, idx[:-1]])
     m = idx.shape[0]
     grid = m // chunk
-    rev = lambda g: ((grid - 1 - g),)  # reverse-order chunk walk
-
+    rows = chunk // _LANES
+    lanes2d = lambda x: x.reshape(m // _LANES, _LANES)
+    inputs = [idx, prev, val]
     if op == "tagged":
         # padding lanes tag 0: the min family, matching the pad identity
-        tg = jnp.concatenate([tags.astype(jnp.int32),
-                              jnp.zeros((pad,), jnp.int32)])
-        kernel = _kernel_tagged
-        inputs = (idx, prev, val, tg)
-    else:
-        kernel = functools.partial(_kernel, op=op)
-        inputs = (idx, prev, val)
+        inputs.append(jnp.concatenate([tags.astype(jnp.int32),
+                                       jnp.zeros((pad,), jnp.int32)]))
+    block = pl.BlockSpec((rows, _LANES), lambda g: (grid - 1 - g, 0))
 
     merged, surv = pl.pallas_call(
-        kernel,
+        functools.partial(_kernel, op=op),
         grid=(grid,),
-        in_specs=[pl.BlockSpec((chunk,), rev)] * len(inputs),
-        out_specs=[
-            pl.BlockSpec((chunk,), rev),
-            pl.BlockSpec((chunk,), rev),
-        ],
+        in_specs=[block] * len(inputs),
+        out_specs=[block, block],
         out_shape=[
-            jax.ShapeDtypeStruct((m,), dt),
-            jax.ShapeDtypeStruct((m,), jnp.int32),
+            jax.ShapeDtypeStruct((m // _LANES, _LANES), dt),
+            jax.ShapeDtypeStruct((m // _LANES, _LANES), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.SMEM((1,), jnp.int32),
-            pltpu.SMEM((1,), dt),
+            pltpu.VMEM((1, 1), jnp.int32),
+            pltpu.VMEM((1, 1), dt),
         ],
+        # the carry threads the blocks in order: the grid axis is sequential
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(*inputs)
-    return merged[:n], surv[:n].astype(jnp.bool_)
+    )(*map(lanes2d, inputs))
+    return merged.reshape(-1)[:n], surv.reshape(-1)[:n].astype(jnp.bool_)

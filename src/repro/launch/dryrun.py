@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 DOC = """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this driver builds the full program — ``train_step`` (model +
@@ -27,6 +24,7 @@ Usage::
 import argparse
 import dataclasses
 import json
+import os
 import subprocess
 import sys
 import time
@@ -138,18 +136,6 @@ def _stage_geometry(cfg: ModelConfig):
     return lead, unit, rep, cfg.encoder_layers
 
 
-def normalize_cost_analysis(cost):
-    """Normalize ``Compiled.cost_analysis()`` across jax versions.
-
-    Older jax returns one properties dict per partition (a list); newer
-    returns the dict directly.  Returns the dict, or None when empty —
-    the single place this quirk is handled (benchmarks import it too).
-    """
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else None
-    return cost
-
-
 def _variant(cfg: ModelConfig, dec_units: int, enc_layers: int) -> ModelConfig:
     lead, unit, _, enc = _stage_geometry(cfg)
     return dataclasses.replace(
@@ -178,7 +164,7 @@ def _measure(cfg_v: ModelConfig, pcfg: ParallelConfig, shape: ShapeConfig,
         compiled = lowered.compile()
         t_compile = time.monotonic() - t0 - t_lower
         mem = compiled.memory_analysis()
-        cost = normalize_cost_analysis(compiled.cost_analysis())
+        cost = compiled.cost_analysis()
         hlo = compiled.as_text()
         del compiled, lowered
     coll = hlo_stats.collective_stats(hlo, n_dev)
@@ -435,4 +421,7 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    # the CLI lowers production meshes on forced host devices; importing
+    # this module leaves the process's devices alone
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     sys.exit(main())

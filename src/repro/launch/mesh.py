@@ -6,18 +6,29 @@ state; the dry-run sets XLA_FLAGS before any jax initialization.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """``jax.make_mesh`` with ``Auto`` axes.
+
+    The model code places activations with ``with_sharding_constraint``
+    (``models.common.constrain``), which binds only to ``Auto`` axes;
+    ``jax.make_mesh`` defaults to ``Explicit`` ones.
+    """
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """TPU v5e: 16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Single-device mesh for CPU smoke tests."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_auto_mesh((1, 1), ("data", "model"))
 
 
 def make_iru_mesh(n_partitions: int = 4):

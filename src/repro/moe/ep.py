@@ -22,7 +22,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import MoEConfig
@@ -96,12 +95,12 @@ def moe_hash_ep(params: dict, x: jax.Array, moe: MoEConfig, ffn_type: str,
         return y[None]                                  # [1, T, D] per device
 
     lane_spec = P()                                     # lane arrays replicated
-    y_parts = shard_map(
+    y_parts = jax.shard_map(
         row_stage, mesh=mesh,
         in_specs=(row_spec, lane_spec, lane_spec, lane_spec, lane_spec,
                   lane_spec) + (P(axis, None, None, None),) * len(weights),
         out_specs=P(axis, None, None),
-        check_rep=False,
+        check_vma=False,
     )(rows, slot_p, plan.keep, plan.partition, plan.src_tok, plan.gate,
       *weights)                                         # [d, T, D] partials
 

@@ -416,7 +416,6 @@ class _PartitionedFusedRuntime:
                  ragged: bool = True):
         import functools
 
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec
         from repro.launch.mesh import make_graph_mesh
 
@@ -458,14 +457,14 @@ class _PartitionedFusedRuntime:
         spec = PartitionSpec(_AXIS)
         rep = PartitionSpec()
         self._step_b = tuple(
-            jax.jit(shard_map(
+            jax.jit(jax.shard_map(
                 functools.partial(self._superstep, bucket=b),
                 mesh=self.mesh, in_specs=(spec, spec, spec),
-                out_specs=(spec, spec, rep), check_rep=False))
+                out_specs=(spec, spec, rep), check_vma=False))
             for b in range(len(self.buckets)))
-        self._predict = jax.jit(shard_map(
+        self._predict = jax.jit(jax.shard_map(
             self._predict_impl, mesh=self.mesh, in_specs=(spec, spec),
-            out_specs=(rep, rep), check_rep=False))
+            out_specs=(rep, rep), check_vma=False))
         self._to_stacked = jax.jit(self._to_stacked_impl)
         self._from_stacked = jax.jit(self._from_stacked_impl)
 

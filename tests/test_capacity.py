@@ -150,6 +150,32 @@ def test_expansion_at_exact_bucket_boundary():
     assert bool(ef.overflow)
 
 
+@pytest.mark.parametrize("order,cap_share", [("ascending", 1.0),
+                                             ("shuffled", 1.0),
+                                             ("shuffled", 0.5)])
+def test_expansion_lanes_match_host_csr_walk(order, cap_share):
+    """Every lane's (src, eid, dst) is the host CSR walk of the frontier in
+    its given order: degree-0 nodes, sentinels and stray negative ids
+    expand to nothing, and a truncated capacity keeps the lane prefix."""
+    g = make_dataset("kron", scale=9)
+    rp, ci = np.asarray(g.row_ptr), np.asarray(g.col_idx)
+    deg = np.diff(rp)
+    rng = np.random.default_rng(5)
+    nodes = rng.choice(g.n_nodes, 200, replace=False)
+    nodes = np.concatenate([nodes, np.nonzero(deg == 0)[0][:5]])
+    nodes = np.sort(nodes) if order == "ascending" else rng.permutation(nodes)
+    f = np.concatenate([nodes, [g.n_nodes, -1, g.n_nodes]]).astype(np.int32)
+    want_eid = np.concatenate([np.arange(rp[v], rp[v + 1]) for v in nodes])
+    want_src = np.repeat(nodes, deg[nodes])
+    cap = int(want_eid.size * cap_share)
+    ef = expand_frontier(g, jnp.asarray(f), edge_capacity=cap)
+    assert bool(ef.overflow) == (cap < want_eid.size)
+    assert int(ef.n_valid) == cap
+    np.testing.assert_array_equal(np.asarray(ef.eids), want_eid[:cap])
+    np.testing.assert_array_equal(np.asarray(ef.srcs), want_src[:cap])
+    np.testing.assert_array_equal(np.asarray(ef.dsts), ci[want_eid[:cap]])
+
+
 def test_frontier_degree_sum_forms_agree(graph):
     rng = np.random.default_rng(3)
     mask = jnp.asarray(rng.random(graph.n_nodes) < 0.2)

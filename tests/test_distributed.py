@@ -30,9 +30,10 @@ def test_sharded_train_step_runs_on_2x4_mesh():
         from repro.configs.base import ParallelConfig, ShapeConfig
         from repro.data.pipeline import make_batch, batch_specs
         from repro.train.trainer import TrainConfig, init_state, make_train_step, abstract_state
+        from repro.launch.mesh import make_auto_mesh
         from repro.launch.shardings import shard_tree, state_shardings
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_auto_mesh((2, 4), ("data", "model"))
         cfg = smoke_config("qwen3-32b")
         pcfg = ParallelConfig(model_axis=4, remat="full", attn_chunk=32)
         tc = TrainConfig(warmup_steps=1, total_steps=10)

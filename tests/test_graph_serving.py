@@ -254,8 +254,11 @@ def test_quarantine_retries_are_bounded_and_fail_loudly(gk):
     bounded retries and lands in status 'failed' with a loud error — the
     supervisor-style giving-up path, never an infinite retry loop."""
     plan = QueryFaultPlan(overflow_at=(1,))
+    # zero backoff: each retry is due on the next tick, so the tick cap
+    # below bounds retries, not how many idle ticks fit in a wall-clock
+    # backoff (about a microsecond each, which made the test flaky)
     eng = GraphServingEngine(
-        gk, GraphServeConfig(query_slots=1, backoff_base_s=0.001,
+        gk, GraphServeConfig(query_slots=1, backoff_base_s=0.0,
                              max_retries=2, capacity_policy=SMALL),
         fault_plan=plan)
     q = GraphQuery("ppr", 0, iters=50, tick_budget=2)

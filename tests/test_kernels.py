@@ -86,7 +86,7 @@ def test_hash_reorder_pallas_engine_matches_ref(n, num_sets, slots, filter_op):
 
 @pytest.mark.parametrize("n", [1, 5, 512, 1000, 4096])
 @pytest.mark.parametrize("op", ["add", "min", "max"])
-@pytest.mark.parametrize("chunk", [64, 512])
+@pytest.mark.parametrize("chunk", [1024, 2048])
 def test_segment_merge_matches_ref(n, op, chunk):
     rng = np.random.default_rng(n + len(op))
     idx = np.sort(rng.integers(0, max(n // 4, 2), n)).astype(np.int32)
@@ -99,11 +99,29 @@ def test_segment_merge_matches_ref(n, op, chunk):
                                np.asarray(mr)[np.asarray(sr)], rtol=1e-5)
 
 
+@pytest.mark.parametrize("n", [5, 1000, 4096])
+def test_segment_merge_tagged_matches_ref(n):
+    """Fused min|add families in one pass; a run's tag is its index's."""
+    rng = np.random.default_rng(n)
+    idx = np.sort(rng.integers(0, max(n // 4, 2), n)).astype(np.int32)
+    val = rng.random(n).astype(np.float32)
+    tags = (idx % 3 == 0)
+    m, surv = segment_merge(jnp.asarray(idx), jnp.asarray(val), op="tagged",
+                            chunk=1024, tags=jnp.asarray(tags))
+    mr, sr = merge_sorted(jnp.asarray(idx), jnp.asarray(val), "tagged",
+                          tags=jnp.asarray(tags))
+    np.testing.assert_array_equal(np.asarray(surv), np.asarray(sr))
+    s = np.asarray(sr)
+    np.testing.assert_array_equal(np.asarray(m)[s & ~tags],
+                                  np.asarray(mr)[s & ~tags])
+    np.testing.assert_allclose(np.asarray(m)[s], np.asarray(mr)[s], rtol=1e-5)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.int32])
 def test_segment_merge_dtypes(dtype):
     idx = jnp.asarray(np.sort(np.random.default_rng(1).integers(0, 30, 256)), jnp.int32)
     val = jnp.arange(256).astype(dtype)
-    m, surv = segment_merge(idx, val, op="min", chunk=64)
+    m, surv = segment_merge(idx, val, op="min", chunk=1024)
     mr, sr = merge_sorted(idx, val, "min")
     np.testing.assert_allclose(np.asarray(m)[np.asarray(surv)],
                                np.asarray(mr)[np.asarray(sr)])
@@ -113,23 +131,23 @@ def test_segment_merge_dtypes(dtype):
 # Coalesced gather kernel
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("rows,d", [(256, 8), (1024, 16), (4096, 4)])
+@pytest.mark.parametrize("rows", [256, 1024, 4096])
 @pytest.mark.parametrize("dtype", [np.float32, np.int32])
-def test_coalesced_gather_sorted_streams(rows, d, dtype):
+def test_coalesced_gather_sorted_streams(rows, dtype):
     rng = np.random.default_rng(rows)
-    table = (rng.random((rows, d)) * 100).astype(dtype)
+    table = (rng.random(rows) * 100).astype(dtype)
     idx = np.sort(rng.integers(0, rows, 512)).astype(np.int32)
-    out = coalesced_gather(jnp.asarray(table), jnp.asarray(idx), group=8, window=128)
+    out = coalesced_gather(jnp.asarray(table), jnp.asarray(idx))
     np.testing.assert_array_equal(np.asarray(out), table[idx])
 
 
 def test_coalesced_gather_fallback_on_scattered_stream():
     """Scattered streams violate the window contract -> baseline gather path."""
     rng = np.random.default_rng(3)
-    table = rng.random((4096, 8)).astype(np.float32)
+    table = rng.random(4096).astype(np.float32)
     idx = rng.integers(0, 4096, 256).astype(np.int32)  # unsorted, wide spread
-    assert not bool(window_contract_ok(jnp.asarray(idx), group=8, window=128))
-    out = coalesced_gather(jnp.asarray(table), jnp.asarray(idx), group=8, window=128)
+    assert not bool(window_contract_ok(jnp.asarray(idx)))
+    out = coalesced_gather(jnp.asarray(table), jnp.asarray(idx))
     np.testing.assert_array_equal(np.asarray(out), table[idx])
 
 
@@ -137,7 +155,7 @@ def test_coalesced_gather_pallas_direct():
     rng = np.random.default_rng(4)
     table = rng.random((1024, 8)).astype(np.float32)
     idx = np.sort(rng.integers(0, 1024, 128)).astype(np.int32)
-    assert bool(window_contract_ok(jnp.asarray(idx), group=8, window=128))
-    out = coalesced_gather_pallas(jnp.asarray(table), jnp.asarray(idx),
-                                  group=8, window=128, interpret=True)
-    np.testing.assert_array_equal(np.asarray(out), table[idx])
+    assert bool(window_contract_ok(jnp.asarray(idx)))
+    cols = coalesced_gather_pallas(tuple(jnp.asarray(table).T),
+                                   jnp.asarray(idx), interpret=True)
+    np.testing.assert_array_equal(np.stack(cols, axis=1), table[idx])

@@ -326,15 +326,6 @@ def test_moe_hash_return_stats():
 # benchmark plumbing + checked-in floors
 # ---------------------------------------------------------------------------
 
-def test_normalize_cost_analysis_list_and_dict():
-    from repro.launch.dryrun import normalize_cost_analysis
-
-    assert normalize_cost_analysis({"flops": 1.0}) == {"flops": 1.0}
-    assert normalize_cost_analysis([{"flops": 2.0}]) == {"flops": 2.0}
-    assert normalize_cost_analysis([]) is None
-    assert normalize_cost_analysis(()) is None
-
-
 def test_checked_in_bench_keeps_moe_floors():
     """MoE rows must exist in the committed BENCH_iru.json and stay above
     the floors: the planned engine's absolute throughput, and the
