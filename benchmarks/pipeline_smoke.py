@@ -32,7 +32,8 @@ def main() -> None:
     pipe = FrontierPipeline(g, BFS_APP, mode="hash", iru_config=cfg,
                             gather="pallas")
     state, mask = pipe.init(source)
-    state, mask, idx, act, real, n_edges, overflow = pipe._step(g, state, mask)
+    state, mask, idx, act, real, n_edges, overflow, _ = pipe._step(
+        g, state, mask, pipe._counts)
     assert int(n_edges) == int(np.asarray(g.degrees())[source]), \
         "first expansion must cover the source's out-edges"
     assert int(np.asarray(act).sum()) > 0

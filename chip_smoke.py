@@ -136,7 +136,7 @@ def pipeline(g, app, mode: str):
 
 def run_args(pipe, source: int = 0):
     state, mask = pipe.init(source)
-    return pipe.graph, state, mask, jnp.int32(0)
+    return pipe.graph, state, mask, jnp.int32(0), pipe._counts
 
 
 def check_fit(label, compiled, host, cap) -> float:

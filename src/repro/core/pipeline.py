@@ -49,6 +49,10 @@ host, dispatching per step, to feed a ``TraceRecorder`` — baseline / sort /
 hash modes are measured from one code path instead of three per-app
 reimplementations.
 
+The pipeline names its own work for the profiler: ``frontier.*`` scopes on
+every stage of the step, ``pipeline.*`` host spans around ``run`` / ``step``,
+and per-rung lane counters read by ``FrontierPipeline.stats()``.
+
 Apps declare themselves as ``FrontierApp`` records: an init rule, a
 per-edge candidate value, a scatter target + merge op, and an update /
 convergence predicate.  See ``apps.bfs.BFS_APP`` etc. for the three paper
@@ -124,6 +128,28 @@ def _scatter(target: jax.Array, idx: jax.Array, val: jax.Array,
     if op == "max":
         return target.at[dest].max(val, mode="drop")
     raise ValueError(f"unknown merge op {op!r}")
+
+
+# Device counters (``FrontierPipeline.stats``) are int32 words: ``lo`` keeps
+# the low ``_COUNT_BITS`` bits and ``hi`` the carries, so a count reaches
+# 2**61 while each step adds under 2**30 (a step's lanes, at most its rung).
+_COUNT_BITS = 30
+_COUNT_FIELDS = ("steps", "live_lanes", "merged_lanes")
+
+
+def _named(name: str, fn: Callable, **kw) -> Callable:
+    """``functools.partial(fn, **kw)`` named ``name``: ``jax.jit`` of it
+    compiles a module called ``jit_<name>`` (``jit__unknown`` otherwise)."""
+    f = functools.partial(fn, **kw)
+    f.__name__ = name
+    return f
+
+
+def _arg_struct(x) -> jax.ShapeDtypeStruct:
+    # no sharding: lowering from it then hits the executable the call
+    # compiled (an explicit single-device sharding lowers another module)
+    return jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                weak_type=getattr(x, "weak_type", False))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -218,32 +244,38 @@ def frontier_step(
     engine frame's own index array, so the tag is always a pure function of
     the destination index and every duplicate run is uniform-tag.
 
+    Each stage runs under a ``jax.named_scope`` (``frontier.expand``,
+    ``frontier.reorder``, ``frontier.scatter``, ``frontier.exchange``,
+    ``frontier.update``): compile-time metadata that names every HLO op's
+    stage in the compiled text and in device traces, at no run-time cost.
+
     Returns ``(state, mask, idx, act, real, n_edges, overflow)``.
     """
     n = g.n_nodes
-    tag_tab = None
-    if app.filter_op == "tagged":
-        if app.tag_table is None:
-            raise ValueError(
-                f"app {app.name!r} has filter_op='tagged' but no tag_table")
-        tag_tab = app.tag_table(state, g)
-    nodes = frontier_from_mask(mask, size=f_cap)
-    ef = expand_frontier(g, nodes, edge_capacity=e_cap, gather=gather,
-                         with_weights=app.needs_weights)
-    vals = app.candidate(state, g, ef)
-    ident = _merge_identity(app.filter_op, vals.dtype)
-    if tag_tab is None:
-        vals = jnp.where(ef.valid, vals, ident)
-    else:
-        # per-lane identity: dead lanes in the ADD family must carry the
-        # add identity (0), not +inf — their family's fold would otherwise
-        # poison the destination through the drop-protected scatter of an
-        # overflowed engine round.  Dead lanes with the sentinel index n
-        # map to tag False and take the min identity as before.
-        lane_tag = tag_tab[jnp.clip(ef.dsts, 0, tag_tab.shape[0] - 1)]
-        ident_add = _merge_identity("add", vals.dtype)
-        vals = jnp.where(ef.valid, vals,
-                         jnp.where(lane_tag, ident_add, ident))
+    with jax.named_scope("frontier.expand"):
+        tag_tab = None
+        if app.filter_op == "tagged":
+            if app.tag_table is None:
+                raise ValueError(f"app {app.name!r} has filter_op='tagged' "
+                                 "but no tag_table")
+            tag_tab = app.tag_table(state, g)
+        nodes = frontier_from_mask(mask, size=f_cap)
+        ef = expand_frontier(g, nodes, edge_capacity=e_cap, gather=gather,
+                             with_weights=app.needs_weights)
+        vals = app.candidate(state, g, ef)
+        ident = _merge_identity(app.filter_op, vals.dtype)
+        if tag_tab is None:
+            vals = jnp.where(ef.valid, vals, ident)
+        else:
+            # per-lane identity: dead lanes in the ADD family must carry the
+            # add identity (0), not +inf — their family's fold would
+            # otherwise poison the destination through the drop-protected
+            # scatter of an overflowed engine round.  Dead lanes with the
+            # sentinel index n map to tag False and take the min identity.
+            lane_tag = tag_tab[jnp.clip(ef.dsts, 0, tag_tab.shape[0] - 1)]
+            ident_add = _merge_identity("add", vals.dtype)
+            vals = jnp.where(ef.valid, vals,
+                             jnp.where(lane_tag, ident_add, ident))
     # the expansion already counted its live lanes (clamped to the
     # bucket) — no O(capacity) reduction to recover it
     n_edges = ef.n_valid
@@ -257,23 +289,27 @@ def frontier_step(
         # Under ragged execution the engines instead treat them as dead
         # lanes: sorts/scans/rounds see the live prefix only, and the
         # pads come back inactive without ever entering a hash set.
-        stream = iru_reorder(ef.dsts, vals, config=iru_config,
-                             n_live=ef.n_valid if ragged else None,
-                             tag_table=tag_tab)
-        idx, svals = stream.indices, stream.secondary
-        act = stream.active & (stream.indices < n)
-        # expansion emits valid lanes front-packed, so a lane is a real
-        # element iff its original position is below the valid count —
-        # what the instrumented driver crops traces to (padding lanes
-        # issue no memory access and must not count in the cost model)
-        real = stream.positions < n_edges
-    lane_tags = (None if tag_tab is None
-                 else tag_tab[jnp.clip(idx, 0, tag_tab.shape[0] - 1)])
-    new_target = _scatter(state[app.target], idx, svals, act, app.filter_op,
-                          tags=lane_tags)
+        with jax.named_scope("frontier.reorder"):
+            stream = iru_reorder(ef.dsts, vals, config=iru_config,
+                                 n_live=ef.n_valid if ragged else None,
+                                 tag_table=tag_tab)
+            idx, svals = stream.indices, stream.secondary
+            act = stream.active & (stream.indices < n)
+            # expansion emits valid lanes front-packed, so a lane is a real
+            # element iff its original position is below the valid count —
+            # what the instrumented driver crops traces to (padding lanes
+            # issue no memory access and must not count in the cost model)
+            real = stream.positions < n_edges
+    with jax.named_scope("frontier.scatter"):
+        lane_tags = (None if tag_tab is None
+                     else tag_tab[jnp.clip(idx, 0, tag_tab.shape[0] - 1)])
+        new_target = _scatter(state[app.target], idx, svals, act,
+                              app.filter_op, tags=lane_tags)
     if exchange is not None:
-        new_target = exchange(new_target, state)
-    state, mask = app.update(state, new_target, g)
+        with jax.named_scope("frontier.exchange"):
+            new_target = exchange(new_target, state)
+    with jax.named_scope("frontier.update"):
+        state, mask = app.update(state, new_target, g)
     return state, mask, idx, act, real, n_edges, ef.overflow
 
 
@@ -415,30 +451,39 @@ class FrontierPipeline:
             self.edge_capacity, graph.n_nodes)
         self.n_traces = 0  # whole-run compiles (tests assert <= n_buckets)
         self.n_hops = 0    # host bucket dispatches across run() calls
-        # whole-run executables donate (state, mask, it): the while_loop
-        # carry rewrites every buffer each level anyway, so the caller's
-        # copies are dead the moment the call is dispatched — donation lets
-        # XLA reuse them instead of allocating a fresh frontier/state set
-        # per run/hop.  run() rebinds all three from the outputs before any
-        # further use.  The per-step executables (_step_b) must NOT donate:
-        # step(raise_on_overflow=False) hands the UNCHANGED inputs back on
-        # overflow and the serving engine re-dispatches them rung by rung.
+        # per-rung device counters (see stats()): [rung, field, (lo, hi)]
+        # words, updated inside the executables and read only by stats()
+        self._counts = jnp.zeros((len(self.buckets), len(_COUNT_FIELDS), 2),
+                                 jnp.int32)
+        # module name -> (jitted fn, argument structs of its first call):
+        # what hlo_texts() looks the compiled executables up by
+        self._signatures: dict[str, tuple[Callable, Any]] = {}
+        # whole-run executables donate (state, mask, it, counts): the
+        # while_loop carry rewrites every buffer each level anyway, so the
+        # caller's copies are dead the moment the call is dispatched —
+        # donation lets XLA reuse them instead of allocating a fresh
+        # frontier/state set per run/hop.  run() rebinds all of them from
+        # the outputs before any further use.  The per-step executables
+        # (_step_b) must NOT donate: step(raise_on_overflow=False) hands the
+        # UNCHANGED inputs back on overflow and the serving engine
+        # re-dispatches them rung by rung.
         self._run_b = tuple(
-            jax.jit(functools.partial(self._run_impl, bucket=b),
-                    donate_argnums=(1, 2, 3))
+            jax.jit(_named(f"frontier_run_r{b}", self._run_impl, bucket=b),
+                    donate_argnums=(1, 2, 3, 4))
             for b in range(len(self.buckets)))
         self._step_b = tuple(
-            jax.jit(functools.partial(self._step_impl, bucket=b))
+            jax.jit(_named(f"frontier_step_r{b}", self._step_impl, bucket=b))
             for b in range(len(self.buckets)))
         # the top-bucket step is the historical fixed-capacity step
         self._step = self._step_b[-1]
-        self._predict = jax.jit(self._predict_impl)
+        self._predict = jax.jit(_named("frontier_predict", self._predict_impl))
 
     # -- bucket dispatch ---------------------------------------------------
     def _predict_impl(self, g, mask):
         """Next iteration's exact working set: (degree sum, node count)."""
-        return (frontier_degree_sum(g, mask),
-                jnp.sum(mask.astype(jnp.int32)))
+        with jax.named_scope("frontier.predict"):
+            return (frontier_degree_sum(g, mask),
+                    jnp.sum(mask.astype(jnp.int32)))
 
     def _host_bucket(self, need: int, count: int) -> int:
         for i, (e_cap, f_cap) in enumerate(self.buckets):
@@ -446,18 +491,41 @@ class FrontierPipeline:
                 return i
         return len(self.buckets) - 1
 
+    def _dispatch(self, fn, *args):
+        """``fn(*args)``, remembering the first call's argument structs."""
+        name = fn.__name__
+        if name not in self._signatures:
+            self._signatures[name] = (fn, jax.tree.map(_arg_struct, args))
+        return fn(*args)
+
     # -- one pipeline iteration (expand → reorder → merge → update) --------
-    def _step_impl(self, g, state, mask, bucket: int):
+    def _step_impl(self, g, state, mask, counts, bucket: int):
         # ``g`` rides as a jit argument (CSRGraph is a pytree), not a baked
         # closure constant: the executable is reusable across same-shape
         # graphs and the HLO carries no giant literals.  ``bucket`` is a
-        # static Python int — one executable per rung.
+        # static Python int — one executable per rung.  ``counts`` comes
+        # back with this step added to the rung's row.
         e_cap, f_cap = self.buckets[bucket]
-        return frontier_step(g, self.app, state, mask, e_cap=e_cap,
-                             f_cap=f_cap, iru_config=self.iru_config,
-                             gather=self.gather, ragged=self.ragged)
+        out = frontier_step(g, self.app, state, mask, e_cap=e_cap,
+                            f_cap=f_cap, iru_config=self.iru_config,
+                            gather=self.gather, ragged=self.ragged)
+        _, _, _, act, _, n_edges, _ = out
+        with jax.named_scope("frontier.count"):
+            # live lanes the reorder merged away (baseline merges none):
+            # ``real & ~act`` counted as ``n_edges - sum(act)``, since only
+            # real lanes are active — reading ``real`` would keep the
+            # engines' position outputs alive, which the loop never reads
+            merged = (jnp.int32(0) if self.iru_config is None
+                      else n_edges.astype(jnp.int32)
+                      - jnp.sum(act.astype(jnp.int32)))
+            lo = counts[bucket, :, 0] + jnp.stack(
+                [jnp.int32(1), n_edges.astype(jnp.int32), merged])
+            hi = counts[bucket, :, 1] + (lo >> _COUNT_BITS)
+            lo = lo & jnp.int32((1 << _COUNT_BITS) - 1)
+            counts = counts.at[bucket].set(jnp.stack([lo, hi], axis=-1))
+        return (*out, counts)
 
-    def _run_impl(self, g, state, mask, it, bucket: int):
+    def _run_impl(self, g, state, mask, it, counts, bucket: int):
         self.n_traces += 1  # python body: executes per trace, not per call
         top = len(self.buckets) - 1
 
@@ -469,7 +537,11 @@ class FrontierPipeline:
         shrunk = self.edge_capacity < self.graph.n_edges
 
         def cond(carry):
-            s, m, i = carry
+            s, m, i, _ = carry
+            with jax.named_scope("frontier.predict"):
+                return fits(s, m, i)
+
+        def fits(s, m, i):
             ok = self.app.cond(s, m) & (i < self.max_iters)
             if top > 0 or shrunk:
                 need, count = self._predict_impl(g, m)
@@ -501,11 +573,14 @@ class FrontierPipeline:
             return ok
 
         def body(carry):
-            s, m, i = carry
-            s, m, *_ = self._step_impl(g, s, m, bucket)
-            return s, m, i + 1
+            s, m, i, c = carry
+            s, m, *_, c = self._step_impl(g, s, m, c, bucket)
+            with jax.named_scope("frontier.count"):
+                return s, m, i + 1, c
 
-        return jax.lax.while_loop(cond, body, (state, mask, it))
+        # the loop's own control, and whatever the compiler makes of it
+        with jax.named_scope("frontier.loop"):
+            return jax.lax.while_loop(cond, body, (state, mask, it, counts))
 
     # -- public drivers ----------------------------------------------------
     def init(self, source: int = 0) -> tuple[State, jax.Array]:
@@ -518,44 +593,64 @@ class FrontierPipeline:
         inside); multi-bucket policies hop executables on the host only
         when the predicted frontier crosses a bucket boundary.  Either
         way ``n_traces <= n_buckets``.
+
+        Host spans (``jax.profiler.TraceAnnotation``, inert unless a
+        profiler trace is on) name the host work: ``pipeline.init`` (the
+        app's init and the donation copies), ``pipeline.hop`` (the loop
+        test, the predict, its host sync and the rung choice),
+        ``pipeline.dispatch`` (enqueueing one rung executable) and
+        ``pipeline.result``.
         """
-        state, mask = self.init(source)
-        # the run executables donate (state, mask, it); donation rejects one
-        # buffer arriving as two leaves (XLA: "donate the same buffer
-        # twice"), and apps may seed several state entries from one array
-        # (ppr's rank/src) — or, worse, reference a graph array, which must
-        # never be given away.  Copy-break duplicates once per run — later
-        # hops pass executable outputs, which are distinct buffers.
-        seen: set[int] = {id(x) for x in jax.tree_util.tree_leaves(self.graph)}
+        span = jax.profiler.TraceAnnotation
+        with span("pipeline.init"):
+            state, mask = self.init(source)
+            # the run executables donate (state, mask, it, counts); donation
+            # rejects one buffer arriving as two leaves (XLA: "donate the
+            # same buffer twice"), and apps may seed several state entries
+            # from one array (ppr's rank/src) — or, worse, reference a
+            # graph array, which must never be given away.  Copy-break
+            # duplicates once per run — later hops pass executable
+            # outputs, which are distinct buffers.
+            seen: set[int] = {
+                id(x) for x in jax.tree_util.tree_leaves(self.graph)}
 
-        def _unalias(x):
-            if id(x) in seen:
-                return jnp.array(x, copy=True)
-            seen.add(id(x))
-            return x
+            def _unalias(x):
+                if id(x) in seen:
+                    return jnp.array(x, copy=True)
+                seen.add(id(x))
+                return x
 
-        state, mask = jax.tree_util.tree_map(_unalias, (state, mask))
-        it = jnp.int32(0)
+            state, mask = jax.tree_util.tree_map(_unalias, (state, mask))
+            it = jnp.int32(0)
         shrunk = self.edge_capacity < self.graph.n_edges
         if len(self.buckets) == 1 and not shrunk:
-            state, _, _ = self._run_b[0](self.graph, state, mask, it)
+            with span("pipeline.dispatch"):
+                state, _, _, self._counts = self._dispatch(
+                    self._run_b[0], self.graph, state, mask, it, self._counts)
         else:
-            while (int(it) < self.max_iters
-                   and bool(self.app.cond(state, mask))):
-                need, count = self._predict(self.graph, mask)
-                if shrunk and int(need) > self.buckets[-1][0]:
-                    raise RuntimeError(
-                        f"frontier degree sum {int(need)} overflows the "
-                        f"shrunk edge_capacity={self.edge_capacity}: edges "
-                        f"would be dropped — raise edge_capacity")
-                b = self._host_bucket(int(need), int(count))
+            while True:
+                with span("pipeline.hop"):
+                    if not (int(it) < self.max_iters
+                            and bool(self.app.cond(state, mask))):
+                        break
+                    need, count = self._dispatch(self._predict, self.graph,
+                                                 mask)
+                    if shrunk and int(need) > self.buckets[-1][0]:
+                        raise RuntimeError(
+                            f"frontier degree sum {int(need)} overflows the "
+                            f"shrunk edge_capacity={self.edge_capacity}: "
+                            f"edges would be dropped — raise edge_capacity")
+                    b = self._host_bucket(int(need), int(count))
                 self.n_hops += 1
-                state, mask, it = self._run_b[b](
-                    self.graph, state, mask, it)
+                with span("pipeline.dispatch"):
+                    state, mask, it, self._counts = self._dispatch(
+                        self._run_b[b], self.graph, state, mask, it,
+                        self._counts)
         assert self.n_traces <= len(self.buckets), (
             f"pipeline traced {self.n_traces}x for "
             f"{len(self.buckets)} buckets — executables not reused")
-        return self.app.result(state)
+        with span("pipeline.result"):
+            return self.app.result(state)
 
     def step(self, state, mask, *, raise_on_overflow: bool = True
              ) -> StepResult:
@@ -568,37 +663,42 @@ class FrontierPipeline:
         ``serve.graph_engine``) build on, and what ``run_instrumented``
         steps.  With ``raise_on_overflow=False`` a top-bucket overflow is
         returned as ``StepResult(overflow=True)`` carrying the UNCHANGED
-        input state/mask (the truncated outputs are discarded) instead of
-        raising, so a serving loop can shed load and retry rather than die.
+        input state/mask (the truncated outputs are discarded, and the
+        step is not counted in ``stats()``) instead of raising, so a
+        serving loop can shed load and retry rather than die.  Host spans
+        as in :meth:`run`.
         """
+        span = jax.profiler.TraceAnnotation
         if len(self.buckets) == 1 and self.edge_capacity >= self.graph.n_edges:
             # default full-capacity single bucket: the choice is forced and
             # a mask-derived frontier cannot overflow n_edges — skip the
             # predict round trip (the pre-bucketing step path exactly)
-            return StepResult(*self._step_b[0](self.graph, state, mask), 0)
-        need, count = self._predict(self.graph, mask)
-        b = self._host_bucket(int(need), int(count))
+            with span("pipeline.dispatch"):
+                *out, self._counts = self._dispatch(
+                    self._step_b[0], self.graph, state, mask, self._counts)
+            return StepResult(*out, 0)
+        with span("pipeline.hop"):
+            need, count = self._dispatch(self._predict, self.graph, mask)
+            b = self._host_bucket(int(need), int(count))
         while True:
-            out = self._step_b[b](self.graph, state, mask)
-            if not bool(out[-1]):  # overflow flag
-                return StepResult(*out[:-1], False, b)
-            if b == len(self.buckets) - 1:
-                if raise_on_overflow:
-                    raise RuntimeError(
-                        f"expansion overflowed the top bucket "
-                        f"(edge_capacity={self.edge_capacity}): the "
-                        f"frontier's degree sum exceeds the compiled "
-                        f"capacity — raise edge_capacity (duplicated "
-                        f"frontier ids can also inflate the degree sum)")
-                return StepResult(state, mask, out[2], out[3], out[4],
-                                  out[5], True, b)
-            b += 1
-
-    def _step_dispatch(self, state, mask):
-        """Back-compat tuple form of :meth:`step`: ``(outputs, bucket)``."""
-        r = self.step(state, mask)
-        return (r.state, r.mask, r.idx, r.act, r.real, r.n_edges,
-                r.overflow), r.bucket
+            with span("pipeline.dispatch"):
+                *out, counts = self._dispatch(
+                    self._step_b[b], self.graph, state, mask, self._counts)
+            with span("pipeline.hop"):
+                if not bool(out[-1]):  # overflow flag
+                    self._counts = counts
+                    return StepResult(*out[:-1], False, b)
+                if b == len(self.buckets) - 1:
+                    if raise_on_overflow:
+                        raise RuntimeError(
+                            f"expansion overflowed the top bucket "
+                            f"(edge_capacity={self.edge_capacity}): the "
+                            f"frontier's degree sum exceeds the compiled "
+                            f"capacity — raise edge_capacity (duplicated "
+                            f"frontier ids can also inflate the degree sum)")
+                    return StepResult(state, mask, out[2], out[3], out[4],
+                                      out[5], True, b)
+                b += 1
 
     def run_instrumented(self, source: int = 0, *, recorder=None) -> jax.Array:
         """Host-stepped traversal over the same compiled steps, feeding a
@@ -610,16 +710,59 @@ class FrontierPipeline:
         state, mask = self.init(source)
         it = 0
         while it < self.max_iters and bool(np.asarray(self.app.cond(state, mask))):
-            (state, mask, idx, act, real, n_edges, _), _ = \
-                self._step_dispatch(state, mask)
+            r = self.step(state, mask)
+            state, mask = r.state, r.mask
             it += 1
             if recorder is not None:
                 if self.mode != "baseline":
-                    recorder.processed(int(n_edges))
+                    recorder.processed(int(r.n_edges))
                 # crop to real-element lanes: recorded streams carry exactly
                 # the accesses the traversal issues, same element counts as
                 # the host apps' ragged traces (capacity padding is free)
-                sel = np.asarray(real)
-                recorder.access(np.asarray(idx)[sel], np.asarray(act)[sel],
+                sel = np.asarray(r.real)
+                recorder.access(np.asarray(r.idx)[sel], np.asarray(r.act)[sel],
                                 atomic=self.app.atomic)
         return self.app.result(state)
+
+    # -- observability -----------------------------------------------------
+    def stats(self) -> dict:
+        """Cumulative counts since construction, as Python ints.
+
+        ``n_traces`` and ``n_hops``, and per rung (ascending, as
+        ``buckets``): ``edge_capacity``, ``steps`` run, ``live_lanes`` (the
+        expansion's live edge lanes, ``EdgeFrontier.n_valid`` summed over
+        the steps), ``compiled_lanes`` (``steps * edge_capacity``) and, in
+        the reorder modes, ``merged_lanes`` (live lanes the reorder left
+        inactive: merged into another lane of the same index).  Steps
+        count in ``run`` and in ``step``; an overflowed step does not.
+        Reads the device counters: one transfer that waits for the work
+        in flight, so call it outside timed work.
+        """
+        words = np.asarray(self._counts).astype(np.int64)
+        totals = (words[..., 0] + (words[..., 1] << _COUNT_BITS)).tolist()
+        rungs = []
+        for (e_cap, _), row in zip(self.buckets, totals):
+            r = {"edge_capacity": e_cap, **dict(zip(_COUNT_FIELDS, row))}
+            r["compiled_lanes"] = r["steps"] * e_cap
+            if self.iru_config is None:
+                del r["merged_lanes"]
+            rungs.append(r)
+        return {"n_traces": self.n_traces, "n_hops": self.n_hops,
+                "rungs": rungs}
+
+    def hlo_texts(self) -> dict[str, str]:
+        """Compiled HLO text of every executable this pipeline has called,
+        by module name (``jit_frontier_run_r<b>``, ``jit_frontier_step_r<b>``,
+        ``jit_frontier_predict``).  Each instruction's ``op_name`` metadata
+        carries its ``frontier.*`` / ``iru.*`` scopes.
+
+        The executables are looked up from the jit caches by the argument
+        structs of their first call: nothing is traced again (``n_traces``
+        is unchanged).  A cache miss would lower and compile again, which
+        ``jax.monitoring``'s compile events show to a caller that listens.
+        """
+        traces = self.n_traces
+        texts = {f"jit_{name}": fn.lower(*args).compile().as_text()
+                 for name, (fn, args) in self._signatures.items()}
+        assert self.n_traces == traces, "hlo_texts() traced a rung again"
+        return texts

@@ -139,25 +139,28 @@ def hash_reorder_banked(
     epb = block_bytes // elem_bytes
     payload = secondary.shape[1:]
 
-    sets = _hash_set(indices // jnp.int32(epb), num_sets)
-    if n_live is None:
-        live = None
-        part = sets % jnp.int32(nP)
-        cap_eff = jnp.int32(C)
-    else:
-        m_live = jnp.clip(jnp.asarray(n_live, jnp.int32), 0, n)
-        live = jnp.arange(n, dtype=jnp.int32) < m_live
-        # sentinel partition: dead lanes never land in a bank row and drop
-        # out of the partition counts (out-of-range scatter indices drop)
-        part = jnp.where(live, sets % jnp.int32(nP), jnp.int32(nP))
-        # the bypass decision the oracle makes on the live prefix:
-        # partition_capacity(m_live, nP), traced (static row width C only
-        # bounds the buffer; capacity is monotone in n so C >= cap_eff)
-        per = (m_live + jnp.int32(nP) - 1) // jnp.int32(nP)
-        cap_eff = jnp.minimum(m_live, per + jnp.maximum(jnp.int32(64),
-                                                        per // 4))
-    cnt = jnp.zeros((nP,), jnp.int32).at[part].add(1)
-    overflow = jnp.max(cnt) > cap_eff
+    with jax.named_scope("iru.route"):
+        sets = _hash_set(indices // jnp.int32(epb), num_sets)
+        if n_live is None:
+            live = None
+            part = sets % jnp.int32(nP)
+            cap_eff = jnp.int32(C)
+        else:
+            m_live = jnp.clip(jnp.asarray(n_live, jnp.int32), 0, n)
+            live = jnp.arange(n, dtype=jnp.int32) < m_live
+            # sentinel partition: dead lanes never land in a bank row and
+            # drop out of the partition counts (out-of-range scatter
+            # indices drop)
+            part = jnp.where(live, sets % jnp.int32(nP), jnp.int32(nP))
+            # the bypass decision the oracle makes on the live prefix:
+            # partition_capacity(m_live, nP), traced (static row width C
+            # only bounds the buffer; capacity is monotone in n so
+            # C >= cap_eff)
+            per = (m_live + jnp.int32(nP) - 1) // jnp.int32(nP)
+            cap_eff = jnp.minimum(m_live, per + jnp.maximum(jnp.int32(64),
+                                                            per // 4))
+        cnt = jnp.zeros((nP,), jnp.int32).at[part].add(1)
+        overflow = jnp.max(cnt) > cap_eff
 
     if bank_map not in ("map", "vmap"):
         raise ValueError(f"bank_map must be 'map' or 'vmap', got {bank_map!r}")
@@ -177,7 +180,7 @@ def hash_reorder_banked(
                                                       rValid))
         return jax.lax.map(row_fn, (rI, rV, rPos, rS, rValid))
 
-    def banked_fn(_):
+    def sort_stage():
         # composite key: partition-major, set-minor, stream-stable — the one
         # big sort of the engine (the flat engine's set sort on a fused key).
         # Built inside the branch so the capacity bypass never pays for it.
@@ -194,7 +197,9 @@ def hash_reorder_banked(
         Pa = part[order]
         part_start = jnp.cumsum(cnt) - cnt
         col = jnp.arange(n, dtype=jnp.int32) - part_start[Pa]
+        return order, S, I, V, Pos, Pa, col
 
+    def bank_rows(S, I, V, Pos, Pa, col):
         # bank buffers: per-partition rows, set-sorted, inert padding at tail
         rc = (Pa, col)
         rI = jnp.full((nP, C), -1, jnp.int32).at[rc].set(I, mode="drop")
@@ -205,8 +210,7 @@ def hash_reorder_banked(
         rValid = jnp.zeros((nP, C), jnp.bool_).at[rc].set(
             jnp.ones((n,), jnp.bool_), mode="drop")
         if mesh is None:
-            oi, osec, opos, oact, m, f = rows_stage(rI, rV, rPos, rS, rValid,
-                                                    tag_table)
+            return rows_stage(rI, rV, rPos, rS, rValid, tag_table)
         else:
             from repro.launch.shardings import iru_partition_axis
 
@@ -225,7 +229,9 @@ def hash_reorder_banked(
             args = (rI, rV, rPos, rS, rValid)
             if tag_table is not None:
                 args = args + (tag_table,)
-            oi, osec, opos, oact, m, f = sharded(*args)
+            return sharded(*args)
+
+    def emit_stage(order, I, V, Pos, oi, osec, opos, oact, m, f):
         # partition-major combine: fronts [0, sum m), tails [n - sum f, n)
         front_off = jnp.cumsum(m) - m
         tail_off = jnp.cumsum(f) - f
@@ -260,14 +266,30 @@ def hash_reorder_banked(
             out_pos = out_pos.at[gd].set(Pos, mode="drop")
         return out_idx, out_sec, out_pos, out_act
 
+    # each arm of the engine, and each stage of the banked arm, runs under
+    # a named scope (``iru.*``): the compiled ops say which arm ran
+    def banked_fn(_):
+        with jax.named_scope("iru.banked"):
+            with jax.named_scope("iru.sort"):
+                order, S, I, V, Pos, Pa, col = sort_stage()
+            with jax.named_scope("iru.rows"):
+                rows = bank_rows(S, I, V, Pos, Pa, col)
+            with jax.named_scope("iru.emit"):
+                return emit_stage(order, I, V, Pos, *rows)
+
     def flat_fn(_):
         # bank capacity exceeded (adversarially skewed stream): bypass
         # banking entirely — same rule as the oracle
-        return hash_reorder_batched(
-            indices, secondary, num_sets=num_sets, slots=slots,
-            elem_bytes=elem_bytes, block_bytes=block_bytes,
-            filter_op=filter_op, round_cap=round_cap, n_live=n_live,
-            tag_table=tag_table)
+        with jax.named_scope("iru.flat"):
+            return hash_reorder_batched(
+                indices, secondary, num_sets=num_sets, slots=slots,
+                elem_bytes=elem_bytes, block_bytes=block_bytes,
+                filter_op=filter_op, round_cap=round_cap, n_live=n_live,
+                tag_table=tag_table)
+
+    def two_gen_fn(plan):
+        with jax.named_scope("iru.two_gen"):
+            return _two_gen_emit(indices, secondary, plan)
 
     if live is not None and _two_gen_fits(n, num_sets):
         # ragged fast path: when every live set stays within two occupancy
@@ -279,15 +301,13 @@ def hash_reorder_banked(
         # ``hash_reorder_ref_banked`` on the live prefix.  The global raw
         # round bound folded into ``ok`` implies every per-partition bound,
         # so no partition the oracle would dense-fallback takes this arm.
-        ok, plan = _two_gen_plan(
-            indices, secondary, live, sets, n_partitions=nP,
-            num_sets=num_sets, slots=slots, filter_op=filter_op,
-            round_cap=round_cap, tag_table=tag_table)
-        branch = jnp.where(overflow, jnp.int32(0),
-                           jnp.where(ok, jnp.int32(2), jnp.int32(1)))
-        return jax.lax.switch(
-            branch,
-            [flat_fn, banked_fn,
-             lambda _: _two_gen_emit(indices, secondary, plan)],
-            None)
+        with jax.named_scope("iru.route"):
+            ok, plan = _two_gen_plan(
+                indices, secondary, live, sets, n_partitions=nP,
+                num_sets=num_sets, slots=slots, filter_op=filter_op,
+                round_cap=round_cap, tag_table=tag_table)
+            branch = jnp.where(overflow, jnp.int32(0),
+                               jnp.where(ok, jnp.int32(2), jnp.int32(1)))
+        return jax.lax.switch(branch, [flat_fn, banked_fn, two_gen_fn],
+                              plan)
     return jax.lax.cond(overflow, flat_fn, banked_fn, None)

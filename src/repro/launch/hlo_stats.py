@@ -1,6 +1,9 @@
 """Roofline-term extraction from compiled XLA artifacts.
 
-``collective_stats`` parses the post-optimization HLO text and models the
+``hlo_instructions`` reads each instruction's opcode and ``op_name``
+metadata (the ``jax.named_scope`` path that issued it) from a compiled
+module's text.  ``collective_stats`` parses the post-optimization HLO text
+and models the
 per-device ICI wire bytes of every collective with ring-algorithm formulas:
 
     all-gather        (n-1)/n * result_bytes
@@ -72,6 +75,84 @@ def _group_size(line: str, default: int) -> int:
     if m:
         return len([x for x in m.group(1).split(",") if x.strip()])
     return default
+
+
+_COMP_RE = re.compile(r"^(?:ENTRY\s+)?%?([^\s(]+)\s.*\{\s*$")
+_INSTR_RE = re.compile(r"^\s+(ROOT\s+)?%?([^\s=]+) = (.*)$")
+_OPCODE_RE = re.compile(r"[\]})] ([a-z][a-z0-9_\-]*)\(")
+_OP_NAME_RE = re.compile(r'\bop_name="([^"]*)"')
+# computations that run as device ops of their own: loop bodies and
+# conditions, conditional branches, called computations
+_RUNS_RE = re.compile(
+    r"\b(?:body|condition|true_computation|false_computation)=%?([^\s,}]+)")
+_BRANCHES_RE = re.compile(r"\bbranch_computations=\{([^}]*)\}")
+_CALLS_RE = re.compile(r"\bcalls=%?([^\s,}]+)")
+
+
+def hlo_instructions(hlo_text: str) -> dict[str, tuple[str, Optional[str]]]:
+    """``{name: (opcode, op_name)}`` for every instruction of a compiled HLO
+    module's text (``Compiled.as_text()``) that runs as a device op: those
+    of the entry computation and of the loop bodies, conditions, branches
+    and calls it reaches, not those inside fusions or reducers.  ``op_name``
+    is the instruction's metadata (its ``jax.named_scope`` path).  An
+    instruction the compiler made without one takes its fused root's, or
+    else that of the loop, branch or call it runs in; None at the top level.
+    Names are unique within a module, not across modules."""
+    comps: dict[str, list] = {}
+    roots: dict[str, Optional[str]] = {}
+    entry, cur = None, None
+    for line in hlo_text.splitlines():
+        m = _COMP_RE.match(line)
+        if m:
+            cur = m.group(1)
+            comps[cur] = []
+            entry = cur if line.startswith("ENTRY") else entry
+            continue
+        m = _INSTR_RE.match(line)
+        if not m or cur is None:
+            continue
+        rest = m.group(3)
+        op = _OPCODE_RE.search(rest)
+        meta = _OP_NAME_RE.search(rest)
+        ins = (m.group(2), op.group(1) if op else "",
+               meta.group(1) if meta else None, rest)
+        comps[cur].append(ins)
+        if m.group(1):
+            roots[cur] = ins[2]
+    out: dict[str, tuple[str, Optional[str]]] = {}
+    todo, seen = [(entry, None)], set()
+    while todo:
+        comp, outer = todo.pop()
+        if comp in seen or comp not in comps:
+            continue
+        seen.add(comp)
+        for name, opcode, op_name, rest in comps[comp]:
+            called = _CALLS_RE.search(rest)
+            runs = _RUNS_RE.findall(rest)
+            for branches in _BRANCHES_RE.findall(rest):
+                runs += [b.strip().lstrip("%") for b in branches.split(",")]
+            if opcode == "fusion":
+                if op_name is None and called:
+                    op_name = roots.get(called.group(1))
+            elif called:
+                runs.append(called.group(1))
+            op_name = outer if op_name is None else op_name
+            todo += [(c, op_name) for c in runs]
+            out[name] = (opcode, op_name)
+    return out
+
+
+def scope_path(op_name: Optional[str], stage: str, within: str) -> tuple:
+    """The innermost component of an ``op_name`` path that starts with
+    ``stage``, then the components under it that start with ``within``:
+    ``("frontier.reorder", "iru.banked", "iru.rows")``.  Empty when no
+    component starts with ``stage``."""
+    parts = (op_name or "").split("/")
+    at = [i for i, c in enumerate(parts) if c.startswith(stage)]
+    if not at:
+        return ()
+    return (parts[at[-1]],) + tuple(
+        c for c in parts[at[-1] + 1:] if c.startswith(within))
 
 
 @dataclasses.dataclass

@@ -318,7 +318,8 @@ def test_bucketed_forced_hop_via_small_min_capacity(graph):
 # ---------------------------------------------------------------------------
 
 def test_step_dispatch_walks_up_on_overflow(graph, monkeypatch):
-    """A lying predictor is corrected by the overflow walk-up, not ignored."""
+    """A lying predictor is corrected by step()'s overflow walk-up, not
+    ignored."""
     pipe = FrontierPipeline(graph, BFS_APP, mode="baseline",
                             capacity_policy=CapacityPolicy(
                                 n_buckets=4, min_capacity=8, growth=8))
@@ -328,17 +329,22 @@ def test_step_dispatch_walks_up_on_overflow(graph, monkeypatch):
     for _ in range(graph.n_nodes):
         if int(frontier_degree_sum(graph, mask)) > pipe.buckets[0][0]:
             break
-        (state, mask, *_), _ = pipe._step_dispatch(state, mask)
+        r = pipe.step(state, mask)
+        state, mask = r.state, r.mask
     need = int(frontier_degree_sum(graph, mask))
     assert need > pipe.buckets[0][0], "frontier never outgrew bucket 0"
     # force dispatch to always start at bucket 0: the step overflows there
-    # and _step_dispatch must walk up to a fitting rung
+    # and step() must walk up to a fitting rung
     monkeypatch.setattr(pipe, "_host_bucket", lambda need, count: 0)
-    out_small = pipe._step_b[0](pipe.graph, state, mask)
-    assert bool(out_small[-1])  # overflowed at the small bucket
-    out, used = pipe._step_dispatch(state, mask)
-    assert used > 0 and not bool(out[-1])
-    assert int(out[5]) == need  # n_edges: nothing truncated
+    out_small = pipe._step_b[0](pipe.graph, state, mask, pipe._counts)
+    assert bool(out_small[6])  # overflowed at the small bucket
+    steps = [r["steps"] for r in pipe.stats()["rungs"]]
+    r = pipe.step(state, mask)
+    assert r.bucket > 0 and not r.overflow
+    assert int(r.n_edges) == need  # n_edges: nothing truncated
+    # only the step that fitted is counted, at the rung it ran on
+    steps[r.bucket] += 1
+    assert [x["steps"] for x in pipe.stats()["rungs"]] == steps
 
 
 def test_overflow_at_top_bucket_raises():

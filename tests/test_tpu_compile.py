@@ -89,7 +89,7 @@ def test_bfs_baseline_top_rung_compiles_for_v5e(sds, gather, monkeypatch):
                                 n_buckets=2, min_capacity=1 << 16))
     state, mask = pipe.init(0)
     args = jax.tree.map(lambda x: sds(x.shape, x.dtype),
-                        (g, state, mask, jnp.int32(0)))
+                        (g, state, mask, jnp.int32(0), pipe._counts))
     compiled = pipe._run_b[-1].lower(*args).compile()
     assert ("tpu_custom_call" in compiled.as_text()) == (gather == "pallas")
     ma = compiled.memory_analysis()
