@@ -76,6 +76,7 @@ from repro.graphs.csr import (
     frontier_degree_sum,
     frontier_from_mask,
 )
+from repro.kernels.iru_reorder.batched import route_prefix_width
 
 State = Any  # pytree of arrays (dict); app-defined
 
@@ -134,7 +135,18 @@ def _scatter(target: jax.Array, idx: jax.Array, val: jax.Array,
 # the low ``_COUNT_BITS`` bits and ``hi`` the carries, so a count reaches
 # 2**61 while each step adds under 2**30 (a step's lanes, at most its rung).
 _COUNT_BITS = 30
-_COUNT_FIELDS = ("steps", "live_lanes", "merged_lanes")
+_COUNT_FIELDS = ("steps", "live_lanes", "merged_lanes", "prefix_route_steps")
+
+
+def _route_width(cfg: Optional[IRUConfig], e_cap: int, ragged: bool) -> int:
+    """Lanes of the hash engine's prefix plan in a rung of ``e_cap`` lanes
+    (``route_prefix_width``); 0 where the rung's reorder has none: no hash
+    engine, a padded stream, or a stream split into windows."""
+    if (cfg is None or cfg.mode != "hash" or cfg.engine != "batched"
+            or not ragged
+            or (cfg.window_elems is not None and e_cap > cfg.window_elems)):
+        return 0
+    return route_prefix_width(e_cap, cfg.num_sets)
 
 
 def _named(name: str, fn: Callable, **kw) -> Callable:
@@ -449,6 +461,10 @@ class FrontierPipeline:
         # ascending (edge_cap, node_cap) rungs; top rung == full capacity
         self.buckets = self.capacity_policy.ladder(
             self.edge_capacity, graph.n_nodes)
+        # per rung: the live count up to which the hash engine's route
+        # plans over its prefix width (0: no prefix plan)
+        self._route_w = tuple(_route_width(self.iru_config, e_cap, ragged)
+                              for e_cap, _ in self.buckets)
         self.n_traces = 0  # whole-run compiles (tests assert <= n_buckets)
         self.n_hops = 0    # host bucket dispatches across run() calls
         # per-rung device counters (see stats()): [rung, field, (lo, hi)]
@@ -518,8 +534,13 @@ class FrontierPipeline:
             merged = (jnp.int32(0) if self.iru_config is None
                       else n_edges.astype(jnp.int32)
                       - jnp.sum(act.astype(jnp.int32)))
+            # a scalar compare: the step's live count fits the route's
+            # prefix width
+            w = self._route_w[bucket]
+            prefix = ((n_edges <= w).astype(jnp.int32) if w
+                      else jnp.int32(0))
             lo = counts[bucket, :, 0] + jnp.stack(
-                [jnp.int32(1), n_edges.astype(jnp.int32), merged])
+                [jnp.int32(1), n_edges.astype(jnp.int32), merged, prefix])
             hi = counts[bucket, :, 1] + (lo >> _COUNT_BITS)
             lo = lo & jnp.int32((1 << _COUNT_BITS) - 1)
             counts = counts.at[bucket].set(jnp.stack([lo, hi], axis=-1))
@@ -733,8 +754,11 @@ class FrontierPipeline:
         expansion's live edge lanes, ``EdgeFrontier.n_valid`` summed over
         the steps), ``compiled_lanes`` (``steps * edge_capacity``) and, in
         the reorder modes, ``merged_lanes`` (live lanes the reorder left
-        inactive: merged into another lane of the same index).  Steps
-        count in ``run`` and in ``step``; an overflowed step does not.
+        inactive: merged into another lane of the same index), and
+        ``prefix_route_steps`` (steps whose live lanes fit the hash
+        engine's prefix plan width, ``route_prefix_width``; 0 where the
+        rung has no such plan).  Steps count in ``run`` and in ``step``;
+        an overflowed step does not.
         Reads the device counters: one transfer that waits for the work
         in flight, so call it outside timed work.
         """

@@ -46,7 +46,7 @@ from repro.kernels.iru_reorder.batched import (
     _reorder_presorted,
     _two_gen_emit,
     _two_gen_fits,
-    _two_gen_plan,
+    _two_gen_route,
     hash_reorder_batched,
 )
 from repro.kernels.iru_reorder.iru_reorder import _hash_set
@@ -299,13 +299,16 @@ def hash_reorder_banked(
         # stage.  Same partition sharding (set % P), same capacity bypass
         # (the ``overflow`` arm), so this is exactly
         # ``hash_reorder_ref_banked`` on the live prefix.  The global raw
-        # round bound folded into ``ok`` implies every per-partition bound,
+        # round bound the route checks implies every per-partition bound,
         # so no partition the oracle would dense-fallback takes this arm.
+        # The route builds the plan only where counts leave it a chance
+        # (no overflow, raw rounds within the cap), and over the live
+        # prefix's width where the live lanes fit it.
         with jax.named_scope("iru.route"):
-            ok, plan = _two_gen_plan(
-                indices, secondary, live, sets, n_partitions=nP,
+            ok, plan = _two_gen_route(
+                indices, secondary, live, m_live, sets, n_partitions=nP,
                 num_sets=num_sets, slots=slots, filter_op=filter_op,
-                round_cap=round_cap, tag_table=tag_table)
+                round_cap=round_cap, tag_table=tag_table, overflow=overflow)
             branch = jnp.where(overflow, jnp.int32(0),
                                jnp.where(ok, jnp.int32(2), jnp.int32(1)))
         return jax.lax.switch(branch, [flat_fn, banked_fn, two_gen_fn],

@@ -254,7 +254,6 @@ def _two_gen_fits(n: int, num_sets: int) -> bool:
 
 def _two_gen_plan(indices, secondary, live, sets, *, n_partitions: int,
                   num_sets: int, slots: int, filter_op: Optional[str],
-                  round_cap: Optional[int],
                   tag_table: Optional[jax.Array] = None):
     """Closed-form analysis of a ragged stream under the *two-generation*
     specialization of the hash oracle, and the exactness guard for it.
@@ -282,10 +281,9 @@ def _two_gen_plan(indices, secondary, live, sets, *, n_partitions: int,
     stable argsort.
 
     Exactness guard (``ok``): no set may start a third generation or flush
-    twice (per-set kept count under ``2 * slots`` whenever it flushed), and
-    with a filter op under a round cap the oracle's dense-fallback rule is
-    decided on the raw live counts — streams past the cap decline the
-    direct path so the presorted machinery applies the fallback.
+    twice (per-set kept count under ``2 * slots`` whenever it flushed).  The
+    round-cap rule is not checked here: :func:`_two_gen_route` decides it
+    before it builds a plan at all.
 
     Returns ``(ok, (outpos, kept, acc))`` — feed to :func:`_two_gen_emit`
     inside the branch ``ok`` selects.
@@ -352,11 +350,6 @@ def _two_gen_plan(indices, secondary, live, sets, *, n_partitions: int,
     kept_s = jnp.zeros((num_sets,), i32).at[sets_l].add(kept.astype(i32))
     flush_s = T[:num_sets] < i32(n)
     ok = jnp.all(jnp.where(flush_s, kept_s < i32(2 * slots), True))
-    if filter_op is not None and round_cap is not None:
-        cnt_s = jnp.zeros((num_sets,), i32).at[sets_l].add(
-            jnp.ones((n,), i32))
-        r_raw = jnp.max((cnt_s + i32(slots) - 1) // i32(slots))
-        ok = ok & (r_raw <= i32(round_cap))
     drain_s = kept_s - jnp.where(flush_s, i32(slots), 0)
 
     # ---- output positions: partition fronts / dead lanes / tails ----------
@@ -416,6 +409,81 @@ def _two_gen_plan(indices, secondary, live, sets, *, n_partitions: int,
              jnp.where(kept, pos_drain,
              jnp.where(filtered, pos_filt, pos_dead)))
     return ok, (outpos, kept, acc)
+
+
+def route_prefix_width(n: int, num_sets: int) -> int:
+    """Static lane width of the two-generation route's prefix plan for an
+    ``n``-lane ragged stream: one sixteenth of ``n`` (the low rung of the
+    capacity ladders), rounded up to a whole 1024-lane tile.  0 where the
+    stream has no prefix plan: the width would not be below ``n``, or the
+    plan's packed sort key would not fit int32."""
+    w = -(-(n // 16) // 1024) * 1024
+    return w if 0 < w < n and _two_gen_fits(n, num_sets) else 0
+
+
+def _two_gen_route(indices, secondary, live, m_live, sets, *,
+                   n_partitions: int, num_sets: int, slots: int,
+                   filter_op: Optional[str], round_cap: Optional[int],
+                   tag_table: Optional[jax.Array] = None, overflow=None):
+    """``(ok, plan)`` of :func:`_two_gen_plan` for a ragged stream, built
+    only over the lanes it needs (``lax.switch``, one branch runs):
+
+    * ``iru.plan_none`` — no plan when counts rule the direct path out: the
+      banks overflow (``overflow``), or with a filter op under a round cap
+      the raw per-set round bound ``max ceil(cnt_s / slots)`` of the live
+      lanes exceeds the cap (the oracle's dense-fallback rule, one
+      histogram).  ``ok`` is False and the plan inert;
+    * ``iru.plan_prefix`` — the live count fits :func:`route_prefix_width`
+      ``W``: the plan of the first ``W`` lanes, lifted to ``n``.  Prefix
+      fronts and dead lanes keep their slots, the filtered tail moves up by
+      ``n - W``, and lanes from ``W`` on (all dead) follow the in-prefix
+      dead lanes;
+    * ``iru.plan_full`` — every other stream: the plan over all ``n`` lanes.
+
+    Every branch gives the ``ok`` and, where ``ok``, the plan of the full
+    width, bit for bit: the same arm runs and emits the same stream.
+    """
+    n = indices.shape[0]
+    i32 = jnp.int32
+    kw = dict(n_partitions=n_partitions, num_sets=num_sets, slots=slots,
+              filter_op=filter_op, tag_table=tag_table)
+    ruled_out = jnp.bool_(False) if overflow is None else overflow
+    if filter_op is not None and round_cap is not None:
+        cnt_s = jnp.zeros((num_sets + 1,), i32).at[
+            jnp.where(live, sets, i32(num_sets))].add(1)
+        r_raw = jnp.max((cnt_s[:num_sets] + i32(slots) - 1) // i32(slots))
+        ruled_out = ruled_out | (r_raw > i32(round_cap))
+
+    def none_fn(_):
+        with jax.named_scope("iru.plan_none"):
+            # inert: every lane in its own slot, none kept; never emitted
+            return jnp.bool_(False), (jnp.arange(n, dtype=i32),
+                                      jnp.zeros((n,), jnp.bool_), secondary)
+
+    def full_fn(_):
+        with jax.named_scope("iru.plan_full"):
+            return _two_gen_plan(indices, secondary, live, sets, **kw)
+
+    W = route_prefix_width(n, num_sets)
+    if not W:
+        return jax.lax.cond(ruled_out, none_fn, full_fn, None)
+
+    def prefix_fn(_):
+        with jax.named_scope("iru.plan_prefix"):
+            ok, (pos_w, kept_w, acc_w) = _two_gen_plan(
+                indices[:W], secondary[:W], live[:W], sets[:W], **kw)
+            n_filt = m_live - jnp.sum(kept_w.astype(i32))
+            pos_w = jnp.where(pos_w >= i32(W) - n_filt, pos_w + i32(n - W),
+                              pos_w)
+            outpos = jnp.concatenate(
+                [pos_w, jnp.arange(W, n, dtype=i32) - n_filt])
+            kept = jnp.concatenate([kept_w, jnp.zeros((n - W,), jnp.bool_)])
+            acc = jnp.concatenate([acc_w, secondary[W:]])
+            return ok, (outpos, kept, acc)
+
+    branch = jnp.where(ruled_out, i32(0),
+                       jnp.where(m_live <= i32(W), i32(1), i32(2)))
+    return jax.lax.switch(branch, [none_fn, prefix_fn, full_fn], None)
 
 
 def _two_gen_emit(indices, secondary, plan):
@@ -698,11 +766,13 @@ def hash_reorder_batched(
         # occupancy wraps at most once); when exact, emission is computed
         # output positions plus one scatter — cheaper than even the padded
         # dense fallback, which is what makes sparse-frontier raggedness a
-        # win rather than a wash
-        ok, plan = _two_gen_plan(
-            indices, secondary, live, sets, n_partitions=1,
-            num_sets=num_sets, slots=slots, filter_op=filter_op,
-            round_cap=round_cap, tag_table=tag_table)
+        # win rather than a wash.  The route sizes the plan to the live
+        # lanes, and builds none where the round cap rules it out
+        with jax.named_scope("iru.route"):
+            ok, plan = _two_gen_route(
+                indices, secondary, live, m_live, sets, n_partitions=1,
+                num_sets=num_sets, slots=slots, filter_op=filter_op,
+                round_cap=round_cap, tag_table=tag_table)
         return jax.lax.cond(
             ok,
             lambda _: _two_gen_emit(indices, secondary, plan),

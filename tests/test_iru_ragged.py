@@ -18,6 +18,9 @@ survivors and filtered tail.  Covers:
   perturb flush timing — ragged execution must flush on live elements only);
 * banked bank-capacity bypass decided on the *live* count, not the padded
   size;
+* the two-generation route sized to the live stream: around the prefix
+  width, past the round cap and past the bank capacity, the arm and the
+  plan are those of the full-width plan;
 * windowed streams: window ``i`` sees ``clip(n_live - i*w, 0, w)`` live lanes;
 * ``n_live == n`` bit-identical to padded execution (no behaviour fork);
 * ``EdgeFrontier.n_valid``: always ``sum(valid)`` and never above the
@@ -46,6 +49,12 @@ from repro.core.pipeline import FrontierPipeline
 from repro.graphs.csr import expand_frontier, from_edges, frontier_from_mask
 from repro.graphs.generators import make_dataset
 from repro.kernels.iru_reorder import ref
+from repro.kernels.iru_reorder.batched import (
+    _two_gen_plan,
+    _two_gen_route,
+    route_prefix_width,
+)
+from repro.kernels.iru_reorder.iru_reorder import _hash_set
 from repro.kernels.iru_reorder.ops import hash_reorder
 
 # ---------------------------------------------------------------------------
@@ -159,6 +168,108 @@ def test_banked_capacity_bypass_decided_on_live_count():
             ref.hash_reorder_ref_banked, idx, sec, m, num_sets=16, slots=8,
             filter_op="min", n_partitions=4)
         _assert_stream(got, want)
+
+
+# ---------------------------------------------------------------------------
+# the two-generation route: plan width from the live count
+# ---------------------------------------------------------------------------
+
+ROUTE_N, ROUTE_SETS, ROUTE_SLOTS, ROUTE_CAP = 4096, 1024, 32, 4
+ROUTE_W = route_prefix_width(ROUTE_N, ROUTE_SETS)
+
+
+def _route_stream(kind, seed):
+    rng = np.random.default_rng(seed)
+    n = ROUTE_N
+    # half the lanes from a narrow range (duplicates to merge, a filtered
+    # tail), half from a wide one (sets stay under two generations)
+    idx = np.where(rng.random(n) < 0.5, rng.integers(0, 2000, n),
+                   rng.integers(0, 200000, n)).astype(np.int32)
+    if kind == "round_cap":
+        # one block 200 times: 7 raw rounds of one set, over the cap of 4,
+        # while every partition stays within its bank capacity
+        idx[rng.choice(n, 200, replace=False)] = 4096
+    elif kind == "wrap":
+        # three blocks of one set, every index once, inside the prefix
+        # width: 96 insertions wrap the set's 32 slots a third time.  They
+        # replace lanes of the set's own partition, so no bank overflows
+        blocks = np.arange(10000, 30000)
+        hot = blocks[ref.hash_set(blocks, ROUTE_SETS) == 5][:3]
+        same = np.flatnonzero(
+            ref.hash_set(idx[:ROUTE_W] // 32, ROUTE_SETS) % 4 == 5 % 4)
+        lanes = rng.choice(same, 96, replace=False)
+        idx[lanes] = (hot[:, None] * 32 + np.arange(32)).reshape(-1)
+    elif kind == "overflow":
+        idx[:] = 128  # every lane in one set, so in one partition
+    return idx, rng.random(n).astype(np.float32)
+
+
+def _parent_arm(idx, m, n_partitions, ok_full):
+    """The arm the full-width plan picked: 0 bank overflow, 1 the banked
+    rows (or the flat engine's presorted path), 2 the two-generation
+    emission."""
+    sets = ref.hash_set(idx[:m] // 32, ROUTE_SETS)
+    part = np.bincount(sets % n_partitions, minlength=n_partitions)
+    if n_partitions > 1 and part.max(initial=0) > ref.partition_capacity(
+            m, n_partitions):
+        return 0
+    r_raw = -(-np.bincount(sets, minlength=ROUTE_SETS).max(initial=0)
+              // ROUTE_SLOTS)
+    return 2 if ok_full and r_raw <= ROUTE_CAP else 1
+
+
+ROUTE_CASES = [(p, kind, m, arm)
+               for p in (4, 1)
+               for kind, m, arm in [
+                   ("mixed", 0, 2), ("mixed", 1, 2),
+                   ("mixed", ROUTE_W - 1, 2), ("mixed", ROUTE_W, 2),
+                   ("mixed", ROUTE_W + 1, 2), ("mixed", ROUTE_N, 2),
+                   ("wrap", ROUTE_W, 1), ("round_cap", ROUTE_N, 1)]]
+ROUTE_CASES.append((4, "overflow", ROUTE_N, 0))
+
+
+@pytest.mark.parametrize(
+    "n_partitions,kind,m,arm", ROUTE_CASES,
+    ids=[f"{'banked' if p > 1 else 'flat'}-{k}-{m}"
+         for p, k, m, _ in ROUTE_CASES])
+def test_two_gen_route_matches_full_width_plan(n_partitions, kind, m, arm):
+    """The route builds no plan, a prefix plan or the full plan from the
+    live count and the per-set counts; the arm it picks, the plan it hands
+    that arm and the stream it emits are the full-width plan's, and the
+    stream is the oracle's on the live prefix.  ``wrap`` is a prefix plan
+    whose own guard declines the direct path."""
+    assert 0 < ROUTE_W < ROUTE_N
+    idx, sec = _route_stream(kind, seed=m + n_partitions)
+    geo = dict(num_sets=ROUTE_SETS, slots=ROUTE_SLOTS, filter_op="min",
+               round_cap=ROUTE_CAP)
+    got = hash_reorder(jnp.asarray(idx), jnp.asarray(sec),
+                       n_partitions=n_partitions, n_live=jnp.int32(m), **geo)
+    oracle = (ref.hash_reorder_ref_banked if n_partitions > 1
+              else ref.hash_reorder_ref_flat)
+    kw = dict(n_partitions=n_partitions) if n_partitions > 1 else {}
+    _assert_stream(got, ref.ragged_oracle(oracle, idx, sec, m, **geo, **kw))
+
+    @jax.jit
+    def plans(i, s, m_live):
+        sets = _hash_set(i // 32, ROUTE_SETS)
+        live = jnp.arange(ROUTE_N) < m_live
+        part = jnp.where(live, sets % n_partitions, n_partitions)
+        cnt = jnp.zeros((n_partitions,), jnp.int32).at[part].add(1)
+        overflow = (jnp.max(cnt) > ref.partition_capacity(m, n_partitions)
+                    if n_partitions > 1 else None)
+        plan_kw = dict(n_partitions=n_partitions, num_sets=ROUTE_SETS,
+                       slots=ROUTE_SLOTS, filter_op="min")
+        return (_two_gen_plan(i, s, live, sets, **plan_kw),
+                _two_gen_route(i, s, live, m_live, sets, round_cap=ROUTE_CAP,
+                               overflow=overflow, **plan_kw))
+
+    (ok_full, full), (ok, plan) = plans(jnp.asarray(idx), jnp.asarray(sec),
+                                        jnp.int32(m))
+    assert _parent_arm(idx, m, n_partitions, bool(ok_full)) == arm
+    assert bool(ok) == (arm == 2)
+    if arm == 2:
+        for a, b in zip(full, plan):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 # ---------------------------------------------------------------------------

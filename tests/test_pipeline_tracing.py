@@ -7,7 +7,11 @@
 * the executables' modules carry stable names;
 * ``stats()`` counts exactly: live lanes are the host reference's
   per-level frontier degree sums, merged lanes the ``hash_ref`` oracle's
-  inactive live lanes, and the counter words carry past 2**30;
+  inactive live lanes, prefix-route steps the levels whose degree sums fit
+  the hash engine's prefix plan width, and the counter words carry past
+  2**30;
+* the hash engine's route names its three plan branches
+  (``iru.plan_none`` / ``iru.plan_prefix`` / ``iru.plan_full``);
 * under a profiler trace the ``pipeline.*`` host spans nest inside the run;
 * ``hlo_texts()`` neither traces nor compiles again.
 """
@@ -26,6 +30,7 @@ from repro.core.iru import _hash_ref_host
 from repro.core.pipeline import FrontierPipeline, _merge_identity
 from repro.graphs.csr import expand_frontier, frontier_from_mask
 from repro.graphs.generators import make_dataset
+from repro.kernels.iru_reorder.batched import route_prefix_width
 from repro.launch.hlo_stats import hlo_instructions, scope_path
 
 BANKED = IRUConfig(num_sets=64, slots=8, n_partitions=4, n_banks=2,
@@ -88,6 +93,22 @@ def test_every_device_op_runs_under_a_stage_scope(ran, case):
                 ("frontier.reorder", "iru.banked", "iru.emit")} <= seen
     else:
         assert "frontier.reorder" not in stages
+
+
+def test_route_names_its_plan_branches(ran):
+    pipe, texts = ran["bfs_hash"]
+    assert pipe.n_traces == len(pipe.buckets) == 2
+    paths = {scope_path(op_name, "frontier.", "iru.")
+             for _, op_name in hlo_instructions(
+                 texts["jit_frontier_run_r1"]).values()}
+    for branch in ("iru.plan_none", "iru.plan_prefix", "iru.plan_full"):
+        assert ("frontier.reorder", "iru.route", branch) in paths, branch
+    # the low rung is below the prefix width's tile: no prefix plan there
+    low = {scope_path(op_name, "frontier.", "iru.")
+           for _, op_name in hlo_instructions(
+               texts["jit_frontier_run_r0"]).values()}
+    assert ("frontier.reorder", "iru.route", "iru.plan_full") in low
+    assert ("frontier.reorder", "iru.route", "iru.plan_prefix") not in low
 
 
 def test_executables_carry_stable_module_names(ran):
@@ -155,6 +176,38 @@ def test_merged_lanes_are_the_oracle_inactive_live_lanes(graph):
         assert got == want
         merged.append(got)
     assert sum(merged) > 0  # the graph's duplicates did merge
+
+
+@pytest.mark.parametrize("mode", ["baseline", "hash"])
+def test_prefix_route_steps_count_the_levels_that_fit_the_width(graph,
+                                                                mode):
+    sums = _level_degree_sums(graph, graph.source)
+    cfg = BANKED if mode == "hash" else None
+    # one rung, through run()
+    pipe = FrontierPipeline(graph, BFS_APP, mode=mode, iru_config=cfg)
+    pipe.run(graph.source)
+    (rung,) = pipe.stats()["rungs"]
+    w = route_prefix_width(rung["edge_capacity"], BANKED.num_sets)
+    want = sum(s <= w for s in sums) if mode == "hash" else 0
+    assert rung["prefix_route_steps"] == want
+    if mode == "hash":
+        assert 0 < want < len(sums)  # levels on both sides of the width
+    # two rungs, step by step: only a rung with a prefix plan counts
+    pipe = FrontierPipeline(graph, BFS_APP, mode=mode, iru_config=cfg,
+                            capacity_policy=_ladder(graph))
+    widths = [route_prefix_width(e_cap, BANKED.num_sets) if cfg else 0
+              for e_cap, _ in pipe.buckets]
+    state, mask = pipe.init(graph.source)
+    want = [0] * len(widths)
+    while bool(jnp.any(mask)):
+        r = pipe.step(state, mask)
+        state, mask = r.state, r.mask
+        want[r.bucket] += int(0 < int(r.n_edges) <= widths[r.bucket])
+    assert [r["prefix_route_steps"]
+            for r in pipe.stats()["rungs"]] == want
+    assert pipe.n_traces == 0  # step() runs the step executables only
+    if mode == "hash":
+        assert widths[0] == 0 < widths[1] and want[1] > 0
 
 
 def test_counter_words_carry_past_2_to_the_30(graph):
