@@ -44,9 +44,9 @@ def main() -> None:
 
     pipe = PartitionedFrontierPipeline(
         part, partitioned_bfs_app(part), mode="hash", compress=True)
-    state, mask = pipe.papp.init(part, 0)
-    ef = np.zeros((4, 4, max(part.lane_cap, 1)), np.float32)
-    state, mask, ef, cont, ovf = pipe._step_b[0](part, state, mask, ef)
+    state, mask, ef = pipe.init(0)
+    state, mask, ef, _, cont, ovf = pipe._step_b[0](pipe.part, state, mask,
+                                                    ef, pipe._counts)
     assert int(cont) > 0 and int(ovf) == 0
     # after one superstep the global frontier is exactly source 0's
     # out-neighborhood (minus the source itself)

@@ -22,7 +22,8 @@ checks every result against the host numpy oracles:
   merge against its reference.
 * ``--chips 4``: ``bfs_partitioned`` / ``sssp_partitioned`` semantics
   through ``PartitionedFrontierPipeline`` (their default ``baseline`` mode)
-  on ``partition_csr(g, 4)`` of a scale-18 graph.
+  on ``partition_csr(g, 4)`` of a scale-18 graph, built on the chips; BFS
+  also against the one-chip pipeline.
 
 Times are smoke output, not benchmark results.  Without a TPU the script
 exits non-zero before any phase.  The last line of stdout is one JSON
@@ -406,7 +407,11 @@ def four_chips(seed: int) -> None:
     """``bfs_partitioned`` / ``sssp_partitioned`` semantics on
     ``partition_csr(g, 4)`` of the PART_SCALE graph, one shard per chip, in
     the wrappers' default (``baseline``) mode: a ``hash`` partitioned step
-    takes minutes to compile, which four chips would sit out."""
+    takes minutes to compile, which four chips would sit out.  BFS is
+    checked against the host oracle and against the one-chip pipeline on
+    chip 0, SSSP against the host oracle."""
+    from repro.apps.bfs import BFS_APP
+    from repro.core.pipeline import FrontierPipeline
     from repro.dist.graph_partition import (PartitionedFrontierPipeline,
                                             partitioned_bfs_app,
                                             partitioned_sssp_app)
@@ -415,24 +420,28 @@ def four_chips(seed: int) -> None:
 
     g, host = build_graph(PART_SCALE, seed)
     hub = int(np.argmax(np.diff(host.row_ptr)))
-    # the host oracles run beside the partition, the compiles and the runs
-    pool = ThreadPoolExecutor(1)
+    # the host oracles and the one-chip BFS run beside the partition, the
+    # compiles and the runs
+    pool = ThreadPoolExecutor(3)
     expected = {name: pool.submit(oracle, host, name, hub)
                 for name in ("bfs", "sssp")}
+    one_chip = pool.submit(
+        lambda: np.asarray(FrontierPipeline(g, BFS_APP).run(hub)))
     t0 = time.monotonic()
-    part = partition_csr(g, 4)
+    part = jax.block_until_ready(partition_csr(g, 4))
     log("partition", shards=4, block=part.block, edge_cap=part.edge_cap,
         ghost_cap=part.ghost_cap, lane_cap=part.lane_cap,
         local_edges=np.asarray(part.n_local_edges).tolist(),
-        partition_s=round(time.monotonic() - t0, 1))
-    del g
+        partition_s=round(time.monotonic() - t0, 2),
+        built_on=[s.device.id for s in part.col_idx.addressable_shards])
     mesh = make_graph_mesh(4)
     makers = {"bfs": partitioned_bfs_app, "sssp": partitioned_sssp_app}
     pipes = {name: PartitionedFrontierPipeline(part, make(part), mesh=mesh)
              for name, make in makers.items()}
     del part
     for name, p in pipes.items():
-        compiled, secs = compile_timed(p._step_b[0], p.part, *p.init(0))
+        compiled, secs = compile_timed(p._step_b[0], p.part, *p.init(0),
+                                       p._counts)
         log(f"compile partitioned {p.mode} {name}", compile_s=round(secs, 1),
             step_bytes_per_device=hbm_bytes(compiled))
         del compiled
@@ -444,10 +453,16 @@ def four_chips(seed: int) -> None:
             raise AssertionError(
                 f"partitioned {name}: {int(np.sum(got != expect))} "
                 f"entries differ from the host oracle")
+        if name == "bfs" and not np.array_equal(got, one_chip.result()):
+            raise AssertionError("partitioned bfs differs from the "
+                                 "one-chip pipeline")
         log(f"run partitioned {p.mode} {name}", source=hub,
             run_s=round(dt, 3), supersteps=p.supersteps,
-            n_traces=p.n_traces, match="bit-identical")
+            n_traces=p.n_traces, stats=p.stats(),
+            match="bit-identical" + (" (host oracle and one chip)"
+                                     if name == "bfs" else ""))
     pool.shutdown()
+    del g
     shards = {s.device.id: s.data.shape
               for s in pipes["bfs"].part.col_idx.addressable_shards}
     for d in jax.devices()[:4]:

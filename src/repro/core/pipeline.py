@@ -138,6 +138,21 @@ _COUNT_BITS = 30
 _COUNT_FIELDS = ("steps", "live_lanes", "merged_lanes", "prefix_route_steps")
 
 
+def _count_add(words: jax.Array, incr: jax.Array) -> jax.Array:
+    """Counter words ``[fields, 2]`` plus ``incr`` (``[fields]`` int32,
+    each under 2**30), carried into the ``hi`` words."""
+    lo = words[:, 0] + incr
+    hi = words[:, 1] + (lo >> _COUNT_BITS)
+    lo = lo & jnp.int32((1 << _COUNT_BITS) - 1)
+    return jnp.stack([lo, hi], axis=-1)
+
+
+def _count_totals(words) -> np.ndarray:
+    """Counter words ``[..., 2]`` as int64 counts ``[...]``, on the host."""
+    w = np.asarray(words).astype(np.int64)
+    return w[..., 0] + (w[..., 1] << _COUNT_BITS)
+
+
 def _route_width(cfg: Optional[IRUConfig], e_cap: int, ragged: bool) -> int:
     """Lanes of the hash engine's prefix plan in a rung of ``e_cap`` lanes
     (``route_prefix_width``); 0 where the rung's reorder has none: no hash
@@ -539,11 +554,10 @@ class FrontierPipeline:
             w = self._route_w[bucket]
             prefix = ((n_edges <= w).astype(jnp.int32) if w
                       else jnp.int32(0))
-            lo = counts[bucket, :, 0] + jnp.stack(
-                [jnp.int32(1), n_edges.astype(jnp.int32), merged, prefix])
-            hi = counts[bucket, :, 1] + (lo >> _COUNT_BITS)
-            lo = lo & jnp.int32((1 << _COUNT_BITS) - 1)
-            counts = counts.at[bucket].set(jnp.stack([lo, hi], axis=-1))
+            counts = counts.at[bucket].set(_count_add(
+                counts[bucket], jnp.stack([jnp.int32(1),
+                                           n_edges.astype(jnp.int32),
+                                           merged, prefix])))
         return (*out, counts)
 
     def _run_impl(self, g, state, mask, it, counts, bucket: int):
@@ -762,8 +776,7 @@ class FrontierPipeline:
         Reads the device counters: one transfer that waits for the work
         in flight, so call it outside timed work.
         """
-        words = np.asarray(self._counts).astype(np.int64)
-        totals = (words[..., 0] + (words[..., 1] << _COUNT_BITS)).tolist()
+        totals = _count_totals(self._counts).tolist()
         rungs = []
         for (e_cap, _), row in zip(self.buckets, totals):
             r = {"edge_capacity": e_cap, **dict(zip(_COUNT_FIELDS, row))}

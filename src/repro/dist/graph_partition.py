@@ -45,7 +45,9 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.iru import IRUConfig
-from repro.core.pipeline import (CapacityPolicy, FrontierApp, _merge_identity,
+from repro.core.pipeline import (_COUNT_FIELDS, CapacityPolicy, FrontierApp,
+                                 _count_add, _count_totals,
+                                 _merge_identity, _named, _route_width,
                                  _scatter, frontier_step)
 from repro.graphs.csr import (CSRGraph, GraphPartition, frontier_degree_sum,
                               partition_csr)
@@ -117,7 +119,9 @@ def _wire_bytes(codec: str, lanes: int, itemsize: int) -> int:
 
 def _boundary_exchange(new_target, ef_buf, *, send_slot, send_mask, recv_id,
                        recv_mask, block, op, codec, payload, tags=None):
-    """One all-to-all of boundary values; returns (merged target, new ef).
+    """One all-to-all of boundary values; returns (merged target, new ef,
+    live lanes): the live lanes are the send lanes that carry a value other
+    than the merge identity, this shard's count.
 
     Runs inside ``shard_map`` per shard.  ``new_target`` is the post-scatter
     local target [local_nodes]: the ghost region [block:] holds this shard's
@@ -155,11 +159,13 @@ def _boundary_exchange(new_target, ef_buf, *, send_slot, send_mask, recv_id,
         rtags = tags[jnp.clip(rid, 0, local_nodes - 1)]
         owned = _scatter(new_target[:block], rid, wire["v"].reshape(-1),
                          recv_mask.reshape(-1), op, tags=rtags)
-        return jnp.concatenate([owned, slot_ident[block:]]), ef_buf
+        live = jnp.sum((send != slot_ident[ss]).astype(jnp.int32))
+        return jnp.concatenate([owned, slot_ident[block:]]), ef_buf, live
     # masked lanes carry the identity so every codec ships a no-op for them
     send = jnp.where(send_mask,
                      new_target[jnp.minimum(send_slot, local_nodes - 1)],
                      ident)
+    live = jnp.sum((send != ident).astype(jnp.int32))
     wire, new_ef = _encode(codec, send, ef_buf, ident)
     wire = jax.tree.map(
         lambda a: jax.lax.all_to_all(a, AXIS, 0, 0, tiled=True), wire)
@@ -167,7 +173,7 @@ def _boundary_exchange(new_target, ef_buf, *, send_slot, send_mask, recv_id,
     owned = _scatter(new_target[:block], recv_id.reshape(-1),
                      recv.reshape(-1), recv_mask.reshape(-1), op)
     ghost = jnp.full((local_nodes - block,), ident, new_target.dtype)
-    return jnp.concatenate([owned, ghost]), new_ef
+    return jnp.concatenate([owned, ghost]), new_ef, live
 
 
 # -- partition-aware apps ---------------------------------------------------
@@ -290,6 +296,11 @@ def partitioned_pagerank_app(part: GraphPartition, *, iters: int = 20,
 
 # -- the partitioned driver -------------------------------------------------
 
+# device counters of the partitioned step: the single-device words, and the
+# lanes each shard's exchange sent with a value other than the identity
+_PART_FIELDS = _COUNT_FIELDS + ("boundary_live_lanes",)
+
+
 class PartitionedFrontierPipeline:
     """Bucketed frontier runtime over an edge-partitioned graph.
 
@@ -303,6 +314,14 @@ class PartitionedFrontierPipeline:
     a ``pmax`` of per-shard working sets so every shard runs the same
     executable.  ``compress=True`` switches the exchange to the app's codec
     (see module docstring); ``compress=False`` is the exact parity path.
+
+    Observability follows ``FrontierPipeline``: host spans in :meth:`run`,
+    per-shard device counters that only :meth:`stats` reads, and
+    :meth:`hlo_texts`.  Rung executables are ``jit_frontier_part_step_r<b>``
+    and the rung fit test ``jit_frontier_part_predict``; the step's stages
+    are ``frontier_step``'s, the counters' updates and the cross-shard
+    flags run under ``frontier.count``, and the shard_map's own operand
+    and result ops under ``frontier.shard_map``.
     """
 
     def __init__(
@@ -326,9 +345,11 @@ class PartitionedFrontierPipeline:
             raise ValueError(
                 f"mesh axis {AXIS!r} has size {mesh.shape.get(AXIS)}, "
                 f"partition has {part.n_parts} shards")
-        # one shard per device, placed once: an unplaced partition would sit
-        # whole on the default device and be re-split on every superstep
-        self.part = jax.device_put(part, NamedSharding(mesh, P(AXIS)))
+        # one shard per device, placed once (``partition_csr`` already
+        # places them so): an unplaced partition would sit whole on the
+        # default device and be re-split on every superstep
+        self.sharding = NamedSharding(mesh, P(AXIS))
+        self.part = jax.device_put(part, self.sharding)
         self.papp = papp
         self.mesh = mesh
         self.mode = mode
@@ -348,23 +369,44 @@ class PartitionedFrontierPipeline:
         # shard's full edge set, so a pmax-dispatched bucket never overflows
         self.buckets = self.capacity_policy.ladder(
             max(part.edge_cap, 1), part.local_nodes)
+        self._route_w = tuple(_route_width(self.iru_config, e_cap, ragged)
+                              for e_cap, _ in self.buckets)
+        self.exchanges = part.n_parts > 1 and part.lane_cap > 0
         self.n_traces = 0
         self.n_hops = 0
-        self.supersteps = 0
+        self.supersteps = 0      # of the last run
+        self.n_supersteps = 0    # since construction
+        self.host_syncs = 0      # device-to-host reads in run(), ditto
         self._state = None
+        # per shard and rung: [P, rung, field, (lo, hi)] words, updated
+        # inside the step executables and read only by stats()
+        self._counts = jax.device_put(
+            jnp.zeros((part.n_parts, len(self.buckets), len(_PART_FIELDS), 2),
+                      jnp.int32), self.sharding)
+        self._signatures: dict[str, tuple[Callable, Any]] = {}
 
-        spec = P(AXIS)
-        rep = P()
+        spec, rep = P(AXIS), P()
+
+        def program(name: str, scope: str, fn, **specs):
+            # jit_<name>; the shard_map's own operand and result ops run
+            # under ``scope``, the body's under its own scopes
+            body = jax.shard_map(fn, mesh=mesh, check_vma=False, **specs)
+
+            def call(*args):
+                with jax.named_scope(scope):
+                    return body(*args)
+            return _named(name, call)
+
         self._step_b = tuple(
-            jax.jit(jax.shard_map(
-                functools.partial(self._superstep, bucket=b),
-                mesh=mesh, in_specs=(spec, spec, spec, spec),
-                out_specs=(spec, spec, spec, rep, rep), check_vma=False),
-                donate_argnums=(1, 2, 3))
+            jax.jit(program(f"frontier_part_step_r{b}", "frontier.shard_map",
+                            functools.partial(self._superstep, bucket=b),
+                            in_specs=(spec,) * 5,
+                            out_specs=(spec,) * 4 + (rep, rep)),
+                    donate_argnums=(1, 2, 3, 4))
             for b in range(len(self.buckets)))
-        self._predict = jax.jit(jax.shard_map(
-            self._predict_impl, mesh=mesh, in_specs=(spec, spec),
-            out_specs=(rep, rep), check_vma=False))
+        self._predict = jax.jit(program(
+            "frontier_part_predict", "frontier.predict", self._predict_impl,
+            in_specs=(spec, spec), out_specs=(rep, rep)))
 
     # -- compiled bodies (run per shard inside shard_map) ------------------
     def _local_graph(self, part: GraphPartition) -> CSRGraph:
@@ -378,7 +420,7 @@ class PartitionedFrontierPipeline:
         count = jnp.sum(m.astype(jnp.int32))
         return jax.lax.pmax(need, AXIS), jax.lax.pmax(count, AXIS)
 
-    def _superstep(self, part, state, mask, ef_buf, *, bucket: int):
+    def _superstep(self, part, state, mask, ef_buf, counts, *, bucket: int):
         self.n_traces += 1  # python body: executes per trace, not per call
         g = self._local_graph(part)
         state = jax.tree.map(lambda a: a[0], state)
@@ -387,12 +429,12 @@ class PartitionedFrontierPipeline:
         e_cap, f_cap = self.buckets[bucket]
 
         exchange = None
-        cell = {"ef": ef_local}
-        if self.part.n_parts > 1 and self.part.lane_cap > 0:
+        cell = {"ef": ef_local, "live": jnp.int32(0)}
+        if self.exchanges:
             def exchange(new_target, st):
                 payload = (None if self.papp.payload is None
                            else self.papp.payload(st))
-                new_target, cell["ef"] = _boundary_exchange(
+                new_target, cell["ef"], cell["live"] = _boundary_exchange(
                     new_target, cell["ef"],
                     send_slot=part.send_slot[0], send_mask=part.send_mask[0],
                     recv_id=part.recv_id[0], recv_mask=part.recv_mask[0],
@@ -400,14 +442,26 @@ class PartitionedFrontierPipeline:
                     codec=self.codec, payload=payload)
                 return new_target
 
-        state, mask, _, _, _, _, overflow = frontier_step(
+        state, mask, _, act, _, n_edges, overflow = frontier_step(
             g, app, state, mask, e_cap=e_cap, f_cap=f_cap,
             iru_config=self.iru_config, gather=self.gather,
             ragged=self.ragged, exchange=exchange)
-        cont = jax.lax.psum(jnp.any(mask).astype(jnp.int32), AXIS)
-        ovf = jax.lax.psum(overflow.astype(jnp.int32), AXIS)
+        with jax.named_scope("frontier.count"):
+            # scalars the step already has, as FrontierPipeline counts them
+            n_edges = n_edges.astype(jnp.int32)
+            merged = (jnp.int32(0) if self.iru_config is None
+                      else n_edges - jnp.sum(act.astype(jnp.int32)))
+            w = self._route_w[bucket]
+            prefix = (n_edges <= w).astype(jnp.int32) if w else jnp.int32(0)
+            counts = counts[0]
+            counts = counts.at[bucket].set(_count_add(
+                counts[bucket], jnp.stack([jnp.int32(1), n_edges, merged,
+                                           prefix, cell["live"]])))
+            cont = jax.lax.psum(jnp.any(mask).astype(jnp.int32), AXIS)
+            ovf = jax.lax.psum(overflow.astype(jnp.int32), AXIS)
         ex = lambda t: jax.tree.map(lambda a: a[None], t)
-        return ex(state), mask[None], cell["ef"][None], cont, ovf
+        return (ex(state), mask[None], cell["ef"][None], counts[None], cont,
+                ovf)
 
     # -- host superstep loop ----------------------------------------------
     def _host_bucket(self, need: int, count: int) -> int:
@@ -415,6 +469,21 @@ class PartitionedFrontierPipeline:
             if need <= e_cap and count <= f_cap:
                 return i
         return len(self.buckets) - 1
+
+    def _dispatch(self, fn, name: str, *args):
+        """``fn(*args)``, remembering the first call's argument structs
+        (with their shardings: the executables take sharded arguments)."""
+        if name not in self._signatures:
+            self._signatures[name] = (fn, jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(
+                    x.shape, x.dtype, sharding=x.sharding,
+                    weak_type=getattr(x, "weak_type", False)), args))
+        return fn(*args)
+
+    def _read(self, x):
+        """One device-to-host read of the run loop, counted."""
+        self.host_syncs += 1
+        return jax.device_get(x)
 
     def init(self, source: int = 0):
         """Initial ``(state, mask, ef_buf)``, placed one shard per device:
@@ -424,36 +493,57 @@ class PartitionedFrontierPipeline:
         state, mask = self.papp.init(part, source)
         ef_buf = jnp.zeros(
             (part.n_parts, part.n_parts, max(part.lane_cap, 1)), jnp.float32)
-        return jax.device_put((state, mask, ef_buf),
-                              NamedSharding(self.mesh, P(AXIS)))
+        return jax.device_put((state, mask, ef_buf), self.sharding)
 
     def run(self, source: int = 0) -> jax.Array:
+        """Supersteps until no shard has a frontier.
+
+        Host spans as in ``FrontierPipeline.run``: ``pipeline.init`` (the
+        app's initial state, placed on the shards), ``pipeline.hop`` (the
+        read of the last superstep's convergence and overflow flags, the
+        fit test and its read, the rung choice), ``pipeline.dispatch``
+        (enqueueing one superstep) and ``pipeline.result``.  Each read is
+        one host sync (``stats()["host_syncs"]``): one per superstep, and
+        one more for the fit test where the ladder has several rungs.
+        """
+        span = jax.profiler.TraceAnnotation
         part = self.part
-        state, mask, ef_buf = self.init(source)
-        self.supersteps = 0
-        last_b = None
-        it, cont = 0, True
+        with span("pipeline.init"):
+            state, mask, ef_buf = self.init(source)
+        last_b, flags, it = None, None, 0
         multi = len(self.buckets) > 1
-        while cont and it < self.max_iters:
-            if multi:
-                need, count = self._predict(part, mask)
-                b = self._host_bucket(int(need), int(count))
-            else:
-                b = 0
-            if b != last_b:
-                self.n_hops += 1
-                last_b = b
-            state, mask, ef_buf, cont_i, ovf = self._step_b[b](
-                part, state, mask, ef_buf)
-            if int(ovf):
-                raise RuntimeError(
-                    f"partitioned superstep overflowed bucket {b} "
-                    f"{self.buckets[b]} — dispatch predicted wrong")
-            cont = int(cont_i) > 0
+        while True:
+            with span("pipeline.hop"):
+                if flags is not None:
+                    cont, ovf = self._read(flags)
+                    if ovf:
+                        raise RuntimeError(
+                            f"partitioned superstep overflowed bucket {b} "
+                            f"{self.buckets[b]} — dispatch predicted wrong")
+                    if not cont:
+                        break
+                if it >= self.max_iters:
+                    break
+                if multi:
+                    need, count = self._read(self._dispatch(
+                        self._predict, "jit_frontier_part_predict", part,
+                        mask))
+                    b = self._host_bucket(int(need), int(count))
+                else:
+                    b = 0
+                if b != last_b:
+                    self.n_hops += 1
+                    last_b = b
+            with span("pipeline.dispatch"):
+                state, mask, ef_buf, self._counts, *flags = self._dispatch(
+                    self._step_b[b], f"jit_frontier_part_step_r{b}", part,
+                    state, mask, ef_buf, self._counts)
             it += 1
         self.supersteps = it
+        self.n_supersteps += it
         self._state = state
-        return self.gather_result(state)
+        with span("pipeline.result"):
+            return self.gather_result(state)
 
     def gather_result(self, state=None) -> jax.Array:
         """Assemble the global [n_nodes] result from the stacked state."""
@@ -462,6 +552,53 @@ class PartitionedFrontierPipeline:
         stacked = self.papp.app.result(state)  # [P, local_nodes]
         owned = stacked[:, :self.part.block]
         return owned.reshape(-1)[:self.part.n_nodes]
+
+    # -- observability -----------------------------------------------------
+    def stats(self) -> dict:
+        """Cumulative counts since construction, as Python ints.
+
+        ``n_traces``, ``n_hops`` (rung changes), ``supersteps`` and
+        ``host_syncs`` (device-to-host reads in ``run``), and per rung
+        (ascending, as ``buckets``): ``edge_capacity`` (a shard's lanes),
+        ``steps`` (supersteps), ``live_lanes`` (the expansions' live edge
+        lanes, summed over shards), ``compiled_lanes`` (``steps *
+        edge_capacity * n_parts``), in the reorder modes ``merged_lanes``,
+        ``prefix_route_steps`` (summed over shards: a shard's step counts
+        where its live lanes fit the prefix plan), ``boundary_lanes`` (the
+        lanes the all-to-all shipped between devices: ``P * (P - 1) *
+        lane_cap`` a superstep), ``boundary_live_lanes`` (those that
+        carried a value other than the merge identity) and ``wire_bytes``
+        (what the codec put on the wire for ``boundary_lanes``).
+        Reads the device counters: one transfer that waits for the work
+        in flight, so call it outside timed work.
+        """
+        totals = _count_totals(self._counts)  # [P, rung, field]
+        p_n, k = self.part.n_parts, self.part.lane_cap
+        rows = p_n * (p_n - 1) if self.exchanges else 0
+        rungs = []
+        for (e_cap, _), shards in zip(self.buckets, totals.transpose(1, 0, 2)):
+            r = dict(zip(_PART_FIELDS, shards.sum(axis=0).tolist()))
+            r["steps"] = int(shards[0, 0])  # lockstep: every shard's count
+            r["compiled_lanes"] = r["steps"] * e_cap * p_n
+            if self.iru_config is None:
+                del r["merged_lanes"]
+            r["boundary_lanes"] = r["steps"] * rows * k
+            r["wire_bytes"] = r["steps"] * rows * _wire_bytes(
+                self.codec, k, self.payload_itemsize)
+            rungs.append({"edge_capacity": e_cap, **r})
+        return {"n_traces": self.n_traces, "n_hops": self.n_hops,
+                "supersteps": self.n_supersteps,
+                "host_syncs": self.host_syncs, "rungs": rungs}
+
+    def hlo_texts(self) -> dict[str, str]:
+        """Compiled HLO text of every executable ``run`` has called, by
+        module name, looked up from the jit caches by the argument structs
+        of the first call: nothing is traced or compiled again."""
+        traces = self.n_traces
+        texts = {name: fn.lower(*args).compile().as_text()
+                 for name, (fn, args) in self._signatures.items()}
+        assert self.n_traces == traces, "hlo_texts() traced a rung again"
+        return texts
 
     # -- boundary-traffic accounting (static: maps are frozen) -------------
     @property

@@ -13,6 +13,7 @@ truncated expansion reports itself through ``EdgeFrontier.overflow``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import NamedTuple
 
 import jax
@@ -485,8 +486,19 @@ def partition_csr(graph: CSRGraph, n_parts: int, *, edge_align: int = 8):
     destinations outside the owned block are renumbered into sorted ghost
     slots.  All shards are padded to common capacities (max local edges,
     max ghosts, max boundary lanes per (shard, owner) pair) so the result
-    stacks into the [P, ...] arrays ``shard_map`` wants.  Pure numpy — runs
-    once per (graph, P) at partition time.
+    stacks into the [P, ...] arrays ``shard_map`` wants.
+
+    Built on the devices: shard ``p``'s edges are one contiguous slice of
+    the CSR, cut out on the graph's device and placed on device ``p`` (one
+    shard per device where there are ``n_parts`` devices, as
+    ``launch.mesh.make_graph_mesh`` lays them out; all on the first device
+    otherwise).  A counting pass marks each shard's remote destinations and
+    returns its ghost count per owner, read back as ``n_parts**2``
+    scalars that fix the static capacities; the build pass then ranks the
+    marks (a ghost's slot is its rank among the shard's remote
+    destinations, so ghosts come out sorted) and writes every array at
+    those sizes on its own device.  The host reads scalars only.  The span
+    ``pipeline.partition`` covers the whole build.
 
     Closed over the view transforms: a :class:`GraphView` input (a
     :func:`tile_csr` composite) returns a :class:`PartitionedGraphView` —
@@ -508,77 +520,151 @@ def partition_csr(graph: CSRGraph, n_parts: int, *, edge_align: int = 8):
         raise ValueError(
             f"partition_csr: n_parts={n_parts} exceeds n_nodes="
             f"{int(graph.n_nodes)} — shards would own no vertices")
-    rp = np.asarray(graph.row_ptr, np.int64)
-    col = np.asarray(graph.col_idx, np.int64)
-    w = np.asarray(graph.weights, np.float32)
-    n = int(graph.n_nodes)
-    m = int(graph.n_edges)
+    with jax.profiler.TraceAnnotation("pipeline.partition"):
+        return _partition_on_devices(graph, n_parts, edge_align)
+
+
+_PART_AXIS = "gpart"  # the graph mesh's axis (launch.mesh.make_graph_mesh)
+
+
+def _partition_on_devices(graph: CSRGraph, n_parts: int,
+                          edge_align: int) -> GraphPartition:
+    n, m = int(graph.n_nodes), int(graph.n_edges)
     block = -(-n // n_parts) if n else 1
+    lo = [min(p * block, n) for p in range(n_parts + 1)]
+    row_ptr = jnp.asarray(graph.row_ptr, jnp.int32)
+    # each shard's edges are the CSR slice [row_ptr[lo_p], row_ptr[hi_p])
+    e = np.asarray(row_ptr[np.asarray(lo)]).tolist()
+    k = [e[p + 1] - e[p] for p in range(n_parts)]
+    edge_cap = max(edge_align, -(-max(max(k), 1) // edge_align) * edge_align)
 
-    segs = []
-    for p in range(n_parts):
-        lo = min(p * block, n)
-        hi = min(lo + block, n)
-        e0, e1 = int(rp[lo]), int(rp[hi])
-        seg_dst = col[e0:e1]
-        owned = (seg_dst >= lo) & (seg_dst < hi)
-        ghosts = np.unique(seg_dst[~owned])  # sorted: owner groups contiguous
-        segs.append((lo, hi, seg_dst, w[e0:e1], owned, ghosts))
+    devices = jax.devices()
+    devices = devices[:n_parts] if len(devices) >= n_parts else devices[:1]
+    mesh = jax.sharding.Mesh(np.asarray(devices), (_PART_AXIS,))
+    sharded = jax.sharding.NamedSharding(
+        mesh, jax.sharding.PartitionSpec(_PART_AXIS))
+    rp_s, col_s, w_s = (
+        _place_rows(rows, mesh, sharded) for rows in _shard_slices(
+            row_ptr, jnp.asarray(graph.col_idx, jnp.int32),
+            jnp.asarray(graph.weights, jnp.float32),
+            bounds=tuple(zip(lo, lo[1:], e, k)), block=block,
+            edge_cap=edge_cap))
+    shard = jax.device_put(
+        (np.asarray(k, np.int32), np.asarray(lo[:-1], np.int32),
+         np.asarray(lo[1:], np.int32)), sharded)
 
-    ghost_cap = max((len(s[5]) for s in segs), default=0)
-    edge_cap = max((len(s[2]) for s in segs), default=0)
-    edge_cap = max(edge_align, -(-max(edge_cap, 1) // edge_align) * edge_align)
-    lane_cap = 0
-    for lo, hi, seg_dst, seg_w, owned, ghosts in segs:
-        if len(ghosts):
-            counts = np.bincount(ghosts // block, minlength=n_parts)
-            lane_cap = max(lane_cap, int(counts.max()))
-
-    local_nodes = block + ghost_cap
-    row_ptr_l = np.zeros((n_parts, local_nodes + 1), np.int32)
-    col_l = np.full((n_parts, edge_cap), local_nodes, np.int32)
-    w_l = np.zeros((n_parts, edge_cap), np.float32)
-    ghost_ids = np.full((n_parts, ghost_cap), -1, np.int32)
-    n_ghosts = np.zeros((n_parts,), np.int32)
-    n_local_edges = np.zeros((n_parts,), np.int32)
-    send_slot = np.full((n_parts, n_parts, lane_cap), local_nodes, np.int32)
-    send_mask = np.zeros((n_parts, n_parts, lane_cap), bool)
-    recv_id = np.full((n_parts, n_parts, lane_cap), block, np.int32)
-    recv_mask = np.zeros((n_parts, n_parts, lane_cap), bool)
-
-    for p, (lo, hi, seg_dst, seg_w, owned, ghosts) in enumerate(segs):
-        deg = rp[lo + 1:hi + 1] - rp[lo:hi]
-        cum = np.concatenate([[0], np.cumsum(deg)])
-        row_ptr_l[p, :hi - lo + 1] = cum
-        row_ptr_l[p, hi - lo + 1:] = cum[-1]  # padding + ghost rows degree-0
-        k = len(seg_dst)
-        col_l[p, :k] = np.where(
-            owned, seg_dst - lo,
-            block + np.searchsorted(ghosts, seg_dst) if len(ghosts)
-            else seg_dst - lo)
-        w_l[p, :k] = seg_w
-        g = len(ghosts)
-        ghost_ids[p, :g] = ghosts
-        n_ghosts[p] = g
-        n_local_edges[p] = k
-        if g:
-            owner = ghosts // block
-            for o in np.unique(owner):
-                idx = np.nonzero(owner == o)[0]
-                send_slot[p, o, :len(idx)] = block + idx
-                send_mask[p, o, :len(idx)] = True
-                recv_id[o, p, :len(idx)] = ghosts[idx] - o * block
-                recv_mask[o, p, :len(idx)] = True
-
+    n_pad = n_parts * block
+    mark, counts = _per_shard(_mark_ghosts, 2, mesh, n_pad=n_pad,
+                              n_parts=n_parts)(col_s, shard)
+    counts_h = np.asarray(counts)  # [P, P]: shard p's ghosts owned by o
+    ghost_cap = int(counts_h.sum(axis=1).max())
+    lane_cap = int(counts_h.max())
+    (row_ptr_l, col_l, w_l, ghost_ids, n_ghosts, send_slot, send_mask,
+     lanes_out, mask_out) = _per_shard(
+        _build_shard, 6, mesh, block=block, ghost_cap=ghost_cap,
+        lane_cap=lane_cap)(rp_s, col_s, w_s, mark, counts, shard)
+    # shard p built the lanes it sends to each owner o; o receives them as
+    # its row p: the transpose is the one cross-device move of the build
+    recv_id, recv_mask = _transpose_rows(mesh)(lanes_out, mask_out)
     return GraphPartition(
-        row_ptr=jnp.asarray(row_ptr_l), col_idx=jnp.asarray(col_l),
-        weights=jnp.asarray(w_l), ghost_ids=jnp.asarray(ghost_ids),
-        n_ghosts=jnp.asarray(n_ghosts),
-        n_local_edges=jnp.asarray(n_local_edges),
-        send_slot=jnp.asarray(send_slot), send_mask=jnp.asarray(send_mask),
-        recv_id=jnp.asarray(recv_id), recv_mask=jnp.asarray(recv_mask),
+        row_ptr=row_ptr_l, col_idx=col_l, weights=w_l, ghost_ids=ghost_ids,
+        n_ghosts=n_ghosts, n_local_edges=shard[0], send_slot=send_slot,
+        send_mask=send_mask, recv_id=recv_id, recv_mask=recv_mask,
         n_nodes=n, n_edges=m, n_parts=n_parts, block=block,
         ghost_cap=ghost_cap, lane_cap=lane_cap, edge_cap=edge_cap)
+
+
+@functools.lru_cache(maxsize=None)
+def _per_shard(fn, n_in: int, mesh, **kw):
+    """``fn`` over every shard as one program on ``mesh``: each device maps
+    it over the shards it holds."""
+    spec = jax.sharding.PartitionSpec(_PART_AXIS)
+    return jax.jit(jax.shard_map(jax.vmap(functools.partial(fn, **kw)),
+                                 mesh=mesh, in_specs=(spec,) * n_in,
+                                 out_specs=spec, check_vma=False))
+
+
+@functools.lru_cache(maxsize=None)
+def _transpose_rows(mesh):
+    """``[P, P, ...]`` arrays with their first two axes swapped, sharded
+    over the first."""
+    sharded = jax.sharding.NamedSharding(
+        mesh, jax.sharding.PartitionSpec(_PART_AXIS))
+    return jax.jit(lambda *xs: tuple(jnp.swapaxes(x, 0, 1) for x in xs),
+                   out_shardings=sharded)
+
+
+def _place_rows(rows, mesh, sharded):
+    """Per-shard rows (each ``[1, ...]``) as one ``[P, ...]`` array, row
+    ``p`` moved to device ``p`` alone; on a one-device mesh, joined there."""
+    if mesh.size == len(rows):
+        return jax.make_array_from_single_device_arrays(
+            (len(rows),) + rows[0].shape[1:], sharded,
+            [jax.device_put(r, d) for r, d in zip(rows, mesh.devices.flat)])
+    return jax.device_put(jnp.concatenate(rows), sharded)
+
+
+@functools.partial(jax.jit, static_argnames=("bounds", "block", "edge_cap"))
+def _shard_slices(row_ptr, col_idx, weights, *, bounds, block, edge_cap):
+    """Each shard's rows and edges, cut from the CSR on the graph's device,
+    one ``[1, ...]`` array per shard: ``row_ptr`` rebased to the shard's
+    first edge (``block + 1`` long, its last value repeated past the
+    shard's rows), and ``col_idx`` / ``weights`` (``edge_cap`` long, padded
+    with -1 / 0).  ``bounds`` holds per shard its first and last row and
+    its first edge and edge count."""
+    rps, cols, ws = [], [], []
+    for lo, hi, e0, k in bounds:
+        rp = row_ptr[lo:hi + 1] - row_ptr[lo]
+        rps.append(jnp.pad(rp, (0, block + lo - hi), mode="edge")[None])
+        cols.append(jnp.pad(col_idx[e0:e0 + k], (0, edge_cap - k),
+                            constant_values=-1)[None])
+        ws.append(jnp.pad(weights[e0:e0 + k], (0, edge_cap - k))[None])
+    return rps, cols, ws
+
+
+def _mark_ghosts(col, shard, *, n_pad: int, n_parts: int):
+    """One shard's remote destinations as marks over the padded global id
+    space, and their count per owning shard."""
+    k, lo, hi = shard
+    live = jnp.arange(col.shape[0]) < k
+    remote = live & ((col < lo) | (col >= hi))
+    mark = jnp.zeros((n_pad,), jnp.bool_).at[
+        jnp.where(remote, col, n_pad)].set(True, mode="drop")
+    return mark, jnp.sum(mark.reshape(n_parts, -1), axis=1, dtype=jnp.int32)
+
+
+def _build_shard(rp, col, w, mark, counts, shard, *, block: int,
+                 ghost_cap: int, lane_cap: int):
+    """One shard's arrays at the static capacities.  A ghost's slot is its
+    rank among the marked ids, so the ghost directory is sorted and each
+    owner's ghosts are one contiguous run of slots, starting at the
+    exclusive prefix sum of the counts."""
+    k, lo, hi = shard
+    local_nodes = block + ghost_cap
+    rank = jnp.cumsum(mark.astype(jnp.int32)) - 1
+    ghost_ids = jnp.full((ghost_cap,), -1, jnp.int32).at[
+        jnp.where(mark, rank, ghost_cap)].set(
+            jnp.arange(mark.shape[0], dtype=jnp.int32), mode="drop")
+    live = jnp.arange(col.shape[0]) < k
+    owned = (col >= lo) & (col < hi)
+    ghost = block + rank[jnp.clip(col, 0, mark.shape[0] - 1)]
+    col_l = jnp.where(live, jnp.where(owned, col - lo, ghost), local_nodes)
+    w_l = jnp.where(live, w, 0.0)
+    row_ptr_l = jnp.concatenate([rp, jnp.full((ghost_cap,), rp[-1])])
+    # lane i of owner o's row carries the o-owned ghost at slot start_o + i
+    start = jnp.cumsum(counts) - counts
+    lane = jnp.arange(lane_cap, dtype=jnp.int32)
+    send_mask = lane[None, :] < counts[:, None]            # [P, lane_cap]
+    slot = start[:, None] + lane[None, :]
+    send_slot = jnp.where(send_mask, block + slot, local_nodes)
+    owner_lo = jnp.arange(counts.shape[0], dtype=jnp.int32)[:, None] * block
+    if ghost_cap:
+        gid = ghost_ids[jnp.minimum(slot, ghost_cap - 1)]
+        recv_lanes = jnp.where(send_mask, gid - owner_lo, block)
+    else:
+        recv_lanes = jnp.full(send_mask.shape, block, jnp.int32)
+    return (row_ptr_l, col_l, w_l, ghost_ids, jnp.sum(counts), send_slot,
+            send_mask, recv_lanes, send_mask)
 
 
 def suggest_partitions(graph: CSRGraph, *, vmem_bytes: int = 16 * 2 ** 20,

@@ -517,7 +517,7 @@ class _PartitionedFusedRuntime:
             def exchange(new_target, st):
                 tag1 = jnp.concatenate(
                     [st["tag"], jnp.zeros((1,), jnp.bool_)])
-                out, _ = _boundary_exchange(
+                out, _, _ = _boundary_exchange(
                     new_target, jnp.float32(0.0),
                     send_slot=part.send_slot[0], send_mask=part.send_mask[0],
                     recv_id=part.recv_id[0], recv_mask=part.recv_mask[0],

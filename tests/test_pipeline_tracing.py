@@ -264,3 +264,149 @@ def test_hlo_texts_trace_and_compile_nothing(ran):
     assert again == texts and pipe.n_traces == traces
     assert "/jax/core/compile/backend_compile_duration" not in compiles
     assert "/jax/core/compile/jaxpr_to_mlir_module_duration" not in compiles
+
+
+# -- the partitioned pipeline, on four forced host devices -----------------
+
+PARTITIONED = r"""
+import glob, json, sys
+import jax
+import numpy as np
+from jax.profiler import ProfileData
+from repro.core import CapacityPolicy
+from repro.dist.graph_partition import (PartitionedFrontierPipeline,
+                                        partitioned_bfs_app)
+from repro.graphs.csr import partition_csr
+from repro.graphs.generators import make_dataset
+from repro.launch.hlo_stats import hlo_instructions, scope_path
+
+g = make_dataset("kron", scale=8)
+source = int(np.argmax(np.diff(np.asarray(g.row_ptr))))
+part = partition_csr(g, 4)
+pipe = PartitionedFrontierPipeline(
+    part, partitioned_bfs_app(part),
+    capacity_policy=CapacityPolicy(n_buckets=2,
+                                   min_capacity=part.edge_cap // 16))
+pipe.run(source)
+out = {"rungs": len(pipe.buckets), "traces": [pipe.n_traces]}
+stats = pipe.stats()
+out["traces"].append(pipe.n_traces)
+texts = pipe.hlo_texts()
+out["traces"].append(pipe.n_traces)
+out["heads"] = {name: t.split(",")[0] for name, t in texts.items()}
+ops = []
+for name, text in texts.items():
+    for ins, (opcode, op_name) in hlo_instructions(text).items():
+        ops.append([name, opcode, op_name,
+                    scope_path(op_name, "frontier.", "iru.")])
+out["ops"] = ops
+
+# the counter words carry past 2**30
+top = (1 << 30) - 1
+before = pipe.stats()["rungs"]
+pipe._counts = jax.device_put(pipe._counts.at[:, :, :, 0].set(top),
+                              pipe.sharding)
+pipe.run(source)
+after = pipe.stats()["rungs"]
+out["carry"] = {"top": top, "before": before, "after": after}
+
+# the host spans of one run, under a trace
+hops = pipe.n_hops
+steps = pipe.n_supersteps
+jax.profiler.start_trace(sys.argv[1])
+with jax.profiler.TraceAnnotation("test.run"):
+    jax.block_until_ready(pipe.run(source))
+jax.profiler.stop_trace()
+pd = ProfileData.from_file(glob.glob(sys.argv[1] + "/**/*.xplane.pb",
+                                     recursive=True)[0])
+out["spans"] = sorted([ev.start_ns, ev.end_ns, ev.name]
+                      for plane in pd.planes if plane.name.startswith("/host:")
+                      for line in plane.lines for ev in line.events
+                      if ev.name.startswith(("pipeline.", "test.run")))
+out["run_steps"] = pipe.n_supersteps - steps
+print("RESULT " + json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def partitioned(tmp_path_factory):
+    import json
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.path.join(root, "src"))
+    p = subprocess.run(
+        [sys.executable, "-c", PARTITIONED,
+         str(tmp_path_factory.mktemp("trace"))],
+        env=env, cwd=root, capture_output=True, text=True, timeout=900)
+    assert p.returncode == 0, p.stderr[-4000:]
+    (res,) = [ln for ln in p.stdout.splitlines() if ln.startswith("RESULT ")]
+    return json.loads(res[len("RESULT "):])
+
+
+def test_every_partitioned_device_op_runs_under_a_stage_scope(partitioned):
+    heads = partitioned["heads"]
+    assert sorted(heads) == ["jit_frontier_part_predict",
+                             "jit_frontier_part_step_r0",
+                             "jit_frontier_part_step_r1"]
+    assert all(h == f"HloModule {m}" for m, h in heads.items())
+    collectives = ("all-to-all", "all-reduce")
+    stages, by_op = set(), {}
+    for name, opcode, op_name, path in partitioned["ops"]:
+        if opcode not in DEVICE_OPS + collectives:
+            continue
+        if op_name is None:
+            # a fusion the CPU compiler made with no metadata (its lowering
+            # of a cumulative sum): outside a loop it has no scope to take;
+            # every gather, scatter, sort and collective has one
+            assert opcode == "fusion", (name, opcode)
+            continue
+        assert path, f"{name}: a {opcode} is unscoped: {op_name!r}"
+        stages.add(path[0])
+        by_op.setdefault(opcode, set()).add(path[0])
+    assert {"frontier.expand", "frontier.scatter", "frontier.exchange",
+            "frontier.update", "frontier.predict", "frontier.count"} <= stages
+    assert stages <= {"frontier.expand", "frontier.scatter",
+                      "frontier.exchange", "frontier.update",
+                      "frontier.predict", "frontier.count",
+                      "frontier.shard_map"}
+    # the boundary all-to-all is the exchange's; the fit test's and the
+    # convergence test's reductions across shards are theirs
+    assert by_op["all-to-all"] == {"frontier.exchange"}
+    assert by_op["all-reduce"] <= {"frontier.predict", "frontier.count"}
+
+
+def test_partitioned_counter_words_carry_past_2_to_the_30(partitioned):
+    c = partitioned["carry"]
+    top = c["top"]
+    for b, a in zip(c["before"], c["after"]):
+        # every shard's words start at top: counts summed over four shards
+        assert a["steps"] == top + (a["steps"] - top)
+        ran = a["steps"] - top
+        assert ran == b["steps"]  # the same traversal again
+        assert a["live_lanes"] == 4 * top + b["live_lanes"]
+        assert a["boundary_live_lanes"] == 4 * top + b["boundary_live_lanes"]
+    assert sum(a["steps"] - top for a in c["after"]) > 0
+
+
+def test_partitioned_pipeline_spans_nest_inside_one_run(partitioned):
+    spans = partitioned["spans"]
+    (r0, r1, _), = [s for s in spans if s[2] == "test.run"]
+    inner = [s for s in spans if s[2] != "test.run"]
+    assert all(r0 <= s0 and s1 <= r1 for s0, s1, _ in inner)
+    names = [n for _, _, n in inner]
+    assert names[0] == "pipeline.init" and names[-1] == "pipeline.result"
+    steps = partitioned["run_steps"]
+    assert names.count("pipeline.dispatch") == steps > 1
+    # a hop span before each dispatch, and the last that ends the run
+    assert names.count("pipeline.hop") == steps + 1
+    assert all(a[1] <= b[0] for a, b in zip(inner, inner[1:]))
+
+
+def test_partitioned_stats_and_texts_trace_nothing(partitioned):
+    rungs = partitioned["rungs"]
+    assert partitioned["traces"] == [rungs, rungs, rungs] == [2, 2, 2]

@@ -94,3 +94,34 @@ def test_bfs_baseline_top_rung_compiles_for_v5e(sds, gather, monkeypatch):
     assert ("tpu_custom_call" in compiled.as_text()) == (gather == "pallas")
     ma = compiled.memory_analysis()
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 16e9
+
+
+def test_partitioned_bfs_superstep_compiles_for_v5e_2x2(topo, monkeypatch):
+    """The four-chip BFS superstep, one shard per chip of the described
+    2x2 host, compiles with its boundary all-to-all and the cross-shard
+    reductions of the flags; a shard's share of a kron-16 graph fits its
+    chip."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from repro.dist import graph_partition as gp
+    from repro.graphs.csr import partition_csr
+
+    part = partition_csr(kron(16, edge_factor=16), 4)
+    mesh = Mesh(topo.devices, ("gpart",))
+    sharded = NamedSharding(mesh, PartitionSpec("gpart"))
+    structs = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharded),
+        tree)
+    # nothing can be placed on a described chip: the pipeline's placements
+    # hand back shapes
+    monkeypatch.setattr(jax, "device_put", lambda x, *a, **k: structs(x))
+    pipe = gp.PartitionedFrontierPipeline(part, gp.partitioned_bfs_app(part),
+                                          mesh=mesh)
+    state, mask = gp.partitioned_bfs_app(part).init(part, 0)
+    ef = jnp.zeros((4, 4, part.lane_cap), jnp.float32)
+    compiled = pipe._step_b[0].lower(
+        pipe.part, *structs((state, mask, ef)), pipe._counts).compile()
+    text = compiled.as_text()
+    assert " all-to-all(" in text and "all-reduce(" in text
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 16e9
